@@ -12,6 +12,7 @@ import time
 import traceback
 
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = ["table1_f1_speedup", "fig3_curves", "fig4_time_per_epoch",
            "fig5_scalability", "fig6_sync_interval", "fig7_straggler",
@@ -22,6 +23,7 @@ BENCHES = ["table1_f1_speedup", "fig3_curves", "fig4_time_per_epoch",
 
 def main() -> int:
     wanted = sys.argv[1:]
+    enable_compile_cache()
     mods = [b for b in BENCHES
             if not wanted or any(w in b for w in wanted)]
     print("name,us_per_call,derived")

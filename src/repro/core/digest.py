@@ -60,7 +60,7 @@ from repro.core.halo_exchange import HaloPrecision
 from repro.core.predictor import PredictorConfig
 from repro.graph.graph import Graph
 from repro.graph.partition import StackedPartitions, build_partitions
-from repro.kernels.spmm import BLOCK_ROWS, STREAM_CHUNK_ROWS
+from repro.kernels.spmm import BLOCK_ROWS, STREAM_CHUNK_ROWS, vma_checkable
 from repro.models.gnn import (GNNConfig, gnn_forward, gnn_forward_sampled,
                               gnn_specs, halo_ref, projected_halo_ref)
 from repro.nn import init_params, micro_f1, softmax_cross_entropy
@@ -293,16 +293,22 @@ def empty_halo_struct(cfg: GNNConfig, struct: dict, rows: int = 8
     return tables, struct
 
 
-def full_graph_forward(cfg: GNNConfig, params: Pytree, data: dict
-                       ) -> jax.Array:
-    """Exact (no staleness, no partition) forward; returns (N_pad, classes)."""
-    x = _subgraph_features(data["x_global"], data["full_ids"][0])
-    # Halo is empty in the M=1 view: all out_nbr are sentinels. Supply
-    # small correctly-shaped zero tables and remap sentinels into them.
-    struct = {k: v[0] for k, v in data["full_struct"].items()}
-    tables, struct = empty_halo_struct(cfg, struct)
-    logits, reps = gnn_forward(cfg, params, x, tables, struct)
-    return logits, reps
+def full_graph_forward(cfg: GNNConfig, params: Pytree, data: dict,
+                       mesh=None) -> jax.Array:
+    """Exact (no staleness, no partition) forward; returns (logits
+    (N_pad, classes), reps).  With ``mesh`` every device computes it
+    whole (:func:`halo_exchange.on_every_device`)."""
+    def forward(params, x_global, full_ids, full_struct):
+        x = _subgraph_features(x_global, full_ids[0])
+        # Halo is empty in the M=1 view: all out_nbr are sentinels. Supply
+        # small correctly-shaped zero tables and remap sentinels into them.
+        struct = {k: v[0] for k, v in full_struct.items()}
+        tables, struct = empty_halo_struct(cfg, struct)
+        return gnn_forward(cfg, params, x, tables, struct)
+
+    return halo_exchange.on_every_device(
+        forward, mesh, check_vma=vma_checkable(cfg.backend))(
+        params, data["x_global"], data["full_ids"], data["full_struct"])
 
 
 def top_layer_reps(cfg: GNNConfig, params: Pytree, data: dict) -> jax.Array:
@@ -603,7 +609,7 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
         if settings.mode == "propagation" and cfg.num_layers > 1:
             # Fresh exchange every epoch: exact reps at current params,
             # gathered down to the per-subgraph halo slabs.
-            _, reps = full_graph_forward(cfg, state["params"], data)
+            _, reps = full_graph_forward(cfg, state["params"], data, mesh)
             ids = jnp.clip(data["halo_ids_x"], 0, reps[0].shape[0] - 1)
             hv = jnp.pad(data["halo_valid"], ((0, 0), (0, 1)))
             if use_projected:
@@ -673,8 +679,10 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
                         **pk))
             return loss_fn(params, x_loc, tables, struct_m, labels, mask)
 
-        vg = jax.vmap(jax.value_and_grad(sub_loss, has_aux=True),
-                      in_axes=(None, 0, 0, 0, 0, 0, 0, 0))
+        vg = halo_exchange.per_subgraph(
+            jax.value_and_grad(sub_loss, has_aux=True), mesh,
+            in_axes=(None, 0, 0, 0, 0, 0, 0, 0), what="per-subgraph loss",
+            check_vma=vma_checkable(cfg.backend))
         (losses, (push_reps, logits)), grads = vg(
             state["params"], x_local, x_halo0, cache, pcache, struct,
             data["labels"], data["train_mask"])
@@ -694,7 +702,7 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
             corr_mask = data["full_train_mask"][0] & sample
 
             def server_loss(p):
-                logits, _ = full_graph_forward(cfg, p, data)
+                logits, _ = full_graph_forward(cfg, p, data, mesh)
                 return softmax_cross_entropy(
                     logits, data["full_labels"][0],
                     corr_mask.astype(jnp.float32))
@@ -793,9 +801,11 @@ def init_state(cfg: GNNConfig, opt: Optimizer, data: dict, seed: int = 0,
     return state
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def evaluate(cfg: GNNConfig, params: Pytree, data: dict) -> dict:
-    logits, _ = full_graph_forward(cfg, params, data)
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+def evaluate(cfg: GNNConfig, params: Pytree, data: dict, mesh=None) -> dict:
+    """Full-graph micro-F1 and loss per split; pass the training ``mesh``
+    when ``params``/``data`` live on it."""
+    logits, _ = full_graph_forward(cfg, params, data, mesh)
     out = {}
     for split in ("train", "val", "test"):
         mask = data[f"full_{split}_mask"][0].astype(jnp.float32)
@@ -866,7 +876,7 @@ def digest_train(cfg: GNNConfig, opt: Optimizer, data: dict,
             state["push_ok"] = jnp.asarray(ok)
         state, m = epoch_fn(state, tdata)
         if (e + 1) % eval_every == 0 or e == epochs - 1:
-            ev = evaluate(cfg, state["params"], tdata)
+            ev = evaluate(cfg, state["params"], tdata, mesh)
             hist["epoch"].append(e + 1)
             hist["loss"].append(float(m["loss"]))
             hist["train_f1"].append(float(m["train_f1"]))
@@ -970,8 +980,11 @@ def make_sampled_epoch_fn(cfg: GNNConfig, opt: Optimizer,
         else:
             hist = jnp.zeros_like(state["hist"])
 
-        vg = jax.vmap(jax.value_and_grad(sub_loss, has_aux=True),
-                      in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
+        vg = halo_exchange.per_subgraph(
+            jax.value_and_grad(sub_loss, has_aux=True), mesh,
+            in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0),
+            what="per-subgraph loss",
+            check_vma=vma_checkable(cfg.backend))
         (losses, (push_reps, logits)), grads = vg(
             state["params"], x_local, x_halo0, cache, pcache, hist,
             data["struct"], data["labels"], batch["seed_mask"],
@@ -1081,7 +1094,7 @@ def sampled_train(cfg: GNNConfig, opt: Optimizer, data: dict, sampler,
         batch = {k: jnp.asarray(v) for k, v in sampler.sample(t).items()}
         state, m = step_fn(state, tdata, batch)
         if (t + 1) % eval_every == 0 or t == steps - 1:
-            ev = evaluate(cfg, state["params"], tdata)
+            ev = evaluate(cfg, state["params"], tdata, mesh)
             hist["epoch"].append(t + 1)
             hist["loss"].append(float(m["loss"]))
             hist["train_f1"].append(float(m["train_f1"]))

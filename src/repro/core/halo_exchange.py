@@ -499,6 +499,60 @@ def shards_per_device(num_parts: int, mesh, axis: str = "data",
     return parts_per_device(num_parts, exchange_size(mesh, axis), what)
 
 
+# A program spanning several devices may hold a Pallas kernel only inside
+# a shard_map whose every mesh axis is manual: XLA cannot partition the
+# kernel's custom call ("Mosaic kernels cannot be automatically
+# partitioned").  Every multi-device path that aggregates goes through one
+# of the two wrappers below.
+
+def per_subgraph(fn, mesh, in_axes: tuple, what: str = "per-subgraph step",
+                 check_vma: bool = True):
+    """``vmap(fn)`` over the stacked subgraph axis M — Algorithm 1's
+    ``for m in parallel``; ``in_axes`` entries are 0 (M-leading) or None
+    (replicated, e.g. the parameters).
+
+    With a mesh the vmap runs inside a ``shard_map`` over the exchange
+    axes, so every device computes its own k = M/devices subgraphs from
+    device-local operands; nothing in ``fn`` may cross subgraphs.  Pass
+    ``check_vma=repro.kernels.spmm.vma_checkable(backend)``."""
+    batched = jax.vmap(fn, in_axes=in_axes)
+    if mesh is None:
+        return batched
+    axes = exchange_axes(mesh)
+    local = P(axes)
+
+    def body(*args):
+        # Replicated operands enter as per-device copies, so a gradient
+        # taken inside ``fn`` is this device's own.  Left invariant, the
+        # varying-axes check would transpose their implicit broadcast
+        # into a sum of the gradients over devices.
+        return batched(*(a if ax == 0 else jax.tree.map(
+            lambda x: jax.lax.pcast(x, axes, to="varying"), a)
+            for a, ax in zip(args, in_axes)))
+
+    def run(*args):
+        m = next(jax.tree.leaves(a)[0].shape[0]
+                 for a, ax in zip(args, in_axes) if ax == 0)
+        shards_per_device(m, mesh, what=what)
+        return jax.shard_map(
+            body, mesh=mesh, out_specs=local, check_vma=check_vma,
+            in_specs=tuple(local if ax == 0 else P() for ax in in_axes),
+        )(*args)
+
+    return run
+
+
+def on_every_device(fn, mesh, check_vma: bool = True):
+    """``fn`` on replicated operands, computed whole by every device of
+    ``mesh`` (plain ``fn`` without one) — the single-graph paths (the
+    full-graph forward of evaluation, the propagation baseline, the LLCG
+    correction) on a mesh that shards the training state."""
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=check_vma)
+
+
 def collective_pull(store: dict, send_offsets: jax.Array,
                     recv_positions: jax.Array, halo_size: int,
                     mesh, axis: str = "data") -> dict:
@@ -524,8 +578,6 @@ def collective_pull(store: dict, send_offsets: jax.Array,
     Raises ValueError when M is not a multiple of the exchange axes
     (pods · data on a multi-pod mesh).
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = exchange_axes(mesh, axis)
     num_data = int(mesh.shape[axis])
     pods = int(mesh.shape["pod"]) if len(axes) == 2 else 1
@@ -599,16 +651,17 @@ def collective_pull(store: dict, send_offsets: jax.Array,
         def _body(data, scale, send, recv):
             return {"data": _exchange(data, send, recv, hidden, 0),
                     "scale": _exchange(scale, send, recv, 1, 1.0)}
-        fn = shard_map(_body, mesh=mesh,
-                       in_specs=(shard, shard, plan, plan),
-                       out_specs={"data": slab_spec, "scale": slab_spec})
+        fn = jax.shard_map(_body, mesh=mesh,
+                           in_specs=(shard, shard, plan, plan),
+                           out_specs={"data": slab_spec,
+                                      "scale": slab_spec})
         return fn(store["data"], store["scale"], send_offsets,
                   recv_positions)
 
     def _body(data, send, recv):
         return {"data": _exchange(data, send, recv, hidden, 0)}
-    fn = shard_map(_body, mesh=mesh, in_specs=(shard, plan, plan),
-                   out_specs={"data": slab_spec})
+    fn = jax.shard_map(_body, mesh=mesh, in_specs=(shard, plan, plan),
+                       out_specs={"data": slab_spec})
     return fn(store["data"], send_offsets, recv_positions)
 
 
@@ -690,8 +743,6 @@ def shard_push(store: dict, local_slots: jax.Array, local_valid: jax.Array,
     local on any mesh shape, only the combined block index e = p·data + d
     changes.  Raises ValueError when M is not a multiple of the
     exchange axes."""
-    from jax.experimental.shard_map import shard_map
-
     axes = exchange_axes(mesh, axis)
     M = local_slots.shape[0]
     k = shards_per_device(M, mesh, axis, "shard_push")
@@ -724,18 +775,19 @@ def shard_push(store: dict, local_slots: jax.Array, local_valid: jax.Array,
     reps_spec = P(axes, None, None, None)
 
     if has_scale:
-        fn = shard_map(_scatter, mesh=mesh,
-                       in_specs=(shard, shard, m_spec, m_spec, reps_spec),
-                       out_specs={"data": shard, "scale": shard})
+        fn = jax.shard_map(_scatter, mesh=mesh,
+                           in_specs=(shard, shard, m_spec, m_spec,
+                                     reps_spec),
+                           out_specs={"data": shard, "scale": shard})
         return fn(store["data"], store["scale"], local_slots, local_valid,
                   reps)
 
     def _body(data, slots, valid, reps_blk):
         return _scatter(data, None, slots, valid, reps_blk)
 
-    fn = shard_map(_body, mesh=mesh,
-                   in_specs=(shard, m_spec, m_spec, reps_spec),
-                   out_specs={"data": shard})
+    fn = jax.shard_map(_body, mesh=mesh,
+                       in_specs=(shard, m_spec, m_spec, reps_spec),
+                       out_specs={"data": shard})
     return fn(store["data"], local_slots, local_valid, reps)
 
 
@@ -827,8 +879,6 @@ def shard_staleness_error(store: dict, fresh: jax.Array,
     :func:`shard_push`: reads stay inside the device's own shards on
     single- and multi-pod meshes (combined block index e = p·data + d).
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = exchange_axes(mesh, axis)
     M, S = local_slots.shape
     k = shards_per_device(M, mesh, axis, "shard_staleness_error")
@@ -855,17 +905,18 @@ def shard_staleness_error(store: dict, fresh: jax.Array,
     out_spec = P(axes, None)
 
     if has_scale:
-        fn = shard_map(_body, mesh=mesh,
-                       in_specs=(shard, shard, reps_spec, m_spec, m_spec),
-                       out_specs=out_spec)
+        fn = jax.shard_map(_body, mesh=mesh,
+                           in_specs=(shard, shard, reps_spec, m_spec,
+                                     m_spec),
+                           out_specs=out_spec)
         per_dev = fn(store["data"], store["scale"], fresh, local_slots,
                      served)
     else:
         def _nb(data, fresh_blk, slots, served_blk):
             return _body(data, None, fresh_blk, slots, served_blk)
-        fn = shard_map(_nb, mesh=mesh,
-                       in_specs=(shard, reps_spec, m_spec, m_spec),
-                       out_specs=out_spec)
+        fn = jax.shard_map(_nb, mesh=mesh,
+                           in_specs=(shard, reps_spec, m_spec, m_spec),
+                           out_specs=out_spec)
         per_dev = fn(store["data"], fresh, local_slots, served)
     # (num_devices, L-1) sharded partial maxima → tiny all-reduce.
     return jnp.max(per_dev, axis=0)

@@ -56,8 +56,8 @@ attention softmax reassociation).
 :func:`serve_query_sharded` — the SPMD form over a mesh: per-part local
 row batches, out-of-shard halo rows pulled via ``collective_pull`` with
 the serving PullPlan, in-shard rows read from the device's own slab
-re-view, the top layer vmapped over parts.  Same split-aggregation
-(in + out) form as the training epoch.
+re-view, the top layer run per part on the device that holds the part.
+Same split-aggregation (in + out) form as the training epoch.
 
 Hot-row cache
 -------------
@@ -99,7 +99,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import halo_exchange
 from repro.core.halo_exchange import PRECISIONS, HaloPrecision
 from repro.graph.partition import PullPlan, build_pull_plan
-from repro.kernels.spmm import halo_gather, halo_spmm
+from repro.kernels.spmm import halo_gather, halo_spmm, vma_checkable
 from repro.nn import dense
 
 
@@ -127,7 +127,7 @@ class ServeConfig:
     storage: str = "fp32"
     # Aggregation backend + halo_spmm selection-ladder overrides for the
     # query-time neighbor reduction (see repro.kernels.spmm.ops).
-    backend: str = "jnp"
+    backend: str = "auto"
     resident_max_bytes: Optional[int] = None
     chunk_rows: Optional[int] = None
     skip_occupancy_max: Optional[float] = None
@@ -565,8 +565,9 @@ def serve_query_sharded(cfg, scfg: ServeConfig, mesh, halo_size: int,
     Out-of-shard halo rows arrive through ``collective_pull`` with the
     serving PullPlan — the ragged all_to_all, zero all-gathers — while
     in-shard rows are read from the device's own slab re-view; the top
-    layer is vmapped over parts in the training epoch's split (in + out)
-    aggregation form.  Returns (M, B, classes) logits.
+    layer runs per part, each device its own parts
+    (:func:`halo_exchange.per_subgraph`), in the training epoch's split
+    (in + out) aggregation form.  Returns (M, B, classes) logits.
     """
     slab = halo_exchange.collective_pull(store_bare(store), sdata["send"],
                                          sdata["recv"], halo_size, mesh)
@@ -593,9 +594,10 @@ def serve_query_sharded(cfg, scfg: ServeConfig, mesh, halo_size: int,
         h_self = jax.vmap(lambda i, d: halo_gather(i, d))(qc, loc)
 
     p = params[f"layer_{cfg.num_layers - 1}"]
-    return jax.vmap(
-        lambda hs, si, so: _batch_top_layer(cfg, scfg, p, hs, [si, so])
-    )(h_self, side_in, side_out)
+    return halo_exchange.per_subgraph(
+        lambda p, hs, si, so: _batch_top_layer(cfg, scfg, p, hs, [si, so]),
+        mesh, in_axes=(None, 0, 0, 0), what="sharded query",
+        check_vma=vma_checkable(scfg.backend))(p, h_self, side_in, side_out)
 
 
 def serve_shardings(store: dict, sdata: dict, mesh, axis: str = "data"):

@@ -9,14 +9,14 @@
   softmax partials that merge exactly across DIGEST's in-subgraph /
   stale-out-of-subgraph edge split.
 """
-from repro.kernels.spmm import spmm, spmm_pallas, spmm_ref
+from repro.kernels.spmm import halo_spmm, spmm, spmm_ref
 from repro.kernels.flash_attention import (attention_ref,
                                            flash_attention_pallas,
                                            multi_head_attention)
 from repro.kernels.gat_edge import (gat_aggregate, gat_edge_partial_pallas,
                                     gat_edge_partial_ref, merge_partials)
 
-__all__ = ["spmm", "spmm_pallas", "spmm_ref", "attention_ref",
+__all__ = ["spmm", "halo_spmm", "spmm_ref", "attention_ref",
            "flash_attention_pallas", "multi_head_attention",
            "gat_aggregate", "gat_edge_partial_pallas",
            "gat_edge_partial_ref", "merge_partials"]

@@ -77,8 +77,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True,
                            sm_scale: float | None = None,
                            block_q: int = DEFAULT_BLOCK_Q,
-                           block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True) -> jax.Array:
+                           block_k: int = DEFAULT_BLOCK_K, *,
+                           interpret: bool) -> jax.Array:
     """q, k, v: (bh, seq, head_dim) — batch*heads flattened on axis 0."""
     bh, seq, hd = q.shape
     if sm_scale is None:
@@ -108,9 +108,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        # jax ≥ 0.7 renamed TPUCompilerParams → CompilerParams; support both.
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -71,7 +71,7 @@ def _gat_kernel(nbr_ref, valid_ref, sdst_ref, ssrc_ref, z_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gat_edge_partial_pallas(nbr: jax.Array, valid: jax.Array,
                             s_dst: jax.Array, s_src: jax.Array,
-                            z: jax.Array, interpret: bool = True
+                            z: jax.Array, *, interpret: bool
                             ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused partial-softmax aggregation.
 

@@ -1,16 +1,17 @@
-from repro.kernels.spmm.halo_pull import (STREAM_CHUNK_ROWS,
+from repro.kernels.spmm.halo_pull import (BLOCK_ROWS, STREAM_CHUNK_ROWS,
                                           halo_spmm_pallas,
                                           halo_spmm_skip_pallas,
                                           halo_spmm_stream_pallas)
-from repro.kernels.spmm.ops import (RESIDENT_STRIPE_MAX_BYTES,
+from repro.kernels.spmm.ops import (BACKENDS, RESIDENT_STRIPE_MAX_BYTES,
                                     SKIP_OCCUPANCY_MAX, halo_gather,
-                                    halo_spmm, spmm)
+                                    halo_spmm, resolve_backend,
+                                    select_kernel, spmm, vma_checkable)
 from repro.kernels.spmm.ref import (halo_spmm_ref, halo_spmm_skip_ref,
                                     spmm_ref)
-from repro.kernels.spmm.spmm import BLOCK_ROWS, spmm_pallas
 
-__all__ = ["spmm", "spmm_ref", "spmm_pallas", "BLOCK_ROWS",
+__all__ = ["spmm", "spmm_ref", "BLOCK_ROWS", "BACKENDS",
            "halo_gather", "halo_spmm", "halo_spmm_ref", "halo_spmm_pallas",
            "halo_spmm_skip_pallas", "halo_spmm_skip_ref",
-           "halo_spmm_stream_pallas", "STREAM_CHUNK_ROWS",
-           "RESIDENT_STRIPE_MAX_BYTES", "SKIP_OCCUPANCY_MAX"]
+           "halo_spmm_stream_pallas", "resolve_backend", "select_kernel",
+           "STREAM_CHUNK_ROWS", "RESIDENT_STRIPE_MAX_BYTES",
+           "SKIP_OCCUPANCY_MAX", "vma_checkable"]

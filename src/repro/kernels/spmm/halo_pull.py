@@ -1,66 +1,62 @@
-"""Pallas TPU kernel: fused halo pull + aggregate over the compact slab.
+"""Pallas TPU kernels: ELL neighbor aggregation over a (quantized) table.
 
-Computes the out-of-subgraph side of DIGEST's Eq. 5
+Computes the aggregation side of DIGEST's Eq. 5
 
-    out[i] = sum_k wts[i, k] * dequant(slab[nbr[i, k]])
+    out[i] = sum_k wts[i, k] * dequant(table[nbr[i, k]])
 
-where ``slab`` is the HaloExchange compact store layer — fp32, bf16, or
-int8 with per-row fp32 scales — and ``nbr`` holds *compact-store slot*
-indices (sentinel == slab.shape[0]-1, a zero row).  Fusing the gather into
-the ELL product means the non-pull epochs of Algorithm 1 never materialize
-the ``(M, L-1, H, hidden)`` halo cache the seed implementation kept: each
-row block reads exactly the slab rows its edges touch, and int8 rows are
-dequantized in-register (VMEM traffic shrinks by the same 2–4× as the
-§3.3 wire format).
+for both sides of the split: the in-subgraph product against the local
+``(S+1, d)`` table, and the out-of-subgraph product against the
+HaloExchange compact slab — fp32, bf16, or int8 with per-row fp32 scales
+— whose ``nbr`` holds slot indices (sentinel == table.shape[0]-1, a zero
+row).  Fusing the gather into the product means the non-pull epochs of
+Algorithm 1 never materialize a ``(M, L-1, H, hidden)`` halo cache, and
+int8 rows are dequantized in VMEM.
 
-Three grid/block designs share one inner loop:
+**Inner loop.**  Mosaic lowers no row gather (``jnp.take`` of a vector of
+row ids from a VMEM table), so each (128-row block × table chunk) pair is
+computed as a dense product on the MXU:
 
-  * **Resident** (:func:`halo_spmm_pallas`): grid = (row_blocks,
-    feature_blocks), the slab carried whole per feature-block into VMEM —
-    int8 slabs fit 4× more rows in the same VMEM budget.  Right while the
-    128-wide slab stripe is ≲ a few MiB (B ≲ 8k fp32 rows).
-  * **Streaming** (:func:`halo_spmm_stream_pallas`): grid = (row_blocks,
-    feature_blocks, slab_chunks) under a ``PrefetchScalarGridSpec`` whose
-    scalar-prefetch argument carries the per-chunk base rows.  The slab
-    enters in ``chunk_rows``-row tiles; because the chunk axis is the
-    innermost grid dimension and the output block index is chunk-
-    invariant, Pallas keeps the accumulator tile resident in VMEM and its
-    pipeline double-buffers the HBM→VMEM DMA of chunk c+1 behind the
-    gather/FMA of chunk c.  VMEM residency is O(chunk) instead of O(B),
-    so web-scale boundary slabs stream at full DMA bandwidth.  Each chunk
-    contributes only the edges whose slot falls inside it (out-of-chunk
-    gathers are masked to weight 0), and partial sums accumulate in fp32
-    across chunks — bitwise-reassociated vs. the resident kernel, equal
-    within dtype tolerance.
+    A[r, c] = sum_k wts[r, k] · [nbr[r, k] == base + c]      (VPU, iota ==)
+    out[r] += (A · scale[base:base+C]) @ chunk                 (MXU, fp32)
 
-  * **Chunk-skipping streamed** (:func:`halo_spmm_skip_pallas`): same
-    tiling as the streaming kernel, but the innermost grid dimension is
-    re-indexed through a precomputed CSR-style worklist
-    (:class:`repro.graph.partition.ChunkWorklist`): grid = (row_blocks,
-    feature_blocks, ``max_chunks_per_block``), and the data BlockSpec's
-    index map reads ``wl_ids[i, t]`` from the scalar-prefetch argument —
-    row block i streams *only the chunks its edges reference* through
-    the same double-buffered pipeline.  Under owner-sharded slot layout
-    halo references cluster by owner, so measured occupancy is far below
-    1 and DMA bytes scale with occupied work, not slab size.  Padded
-    worklist entries repeat the last visited chunk (the resident block is
-    re-addressed, no DMA) and are masked out of the FMA (``t >= cnt``),
-    so the result is **bitwise identical** to the dense stream with the
-    same ``chunk_rows``: skipped chunks contribute exact ±0.0 terms,
-    which never perturb an fp32 accumulator.
+The ELL arrays enter transposed, ``(deg, rows)``, so the degree loop reads
+one sublane row per step (a dynamic *sublane* slice, which Mosaic lowers;
+a dynamic lane slice it does not) and builds Aᵀ with a sublane iota; one
+transpose per chunk turns it into A.  The int8 dequant scale rides as a
+lane-dense ``(1, rows)`` row folded into A's columns, so no ``(n, 1)``
+column pads to 128 lanes in VMEM.  The SAT predictor epilogue (``pdata``
+/ ``pscale`` / ``gamma``, see ``repro.core.predictor``) is a second
+product ``(A · γ·pscale) @ pchunk`` over the same A — never a second
+aggregation pass; ``gamma`` is a static (jit-cache-keyed) float, and with
+``pdata=None`` the emitted kernel is exactly the predictor-free one.
 
-Per-row scales ride along as a (rows, 1) fp32 column and are folded into
-the edge weight (``w · scale[idx]``) before the FMA, so the inner loop
-stays a gather + single fused multiply-add in all three designs.
+The A tile costs ``deg × 128 × C`` VPU compares per (block, chunk) pair,
+so work grows with rows × table rows; the chunk-skipping grid below is
+what keeps it proportional to the pairs the graph actually occupies.
 
-**Staleness-alleviated prediction epilogue** (``pdata`` / ``pscale`` /
-``gamma``): when the SAT predictor is on (see ``repro.core.predictor``),
-the history slab rides beside the data slab through the SAME BlockSpecs
-and the gathered row becomes ``dequant(data[s]) + γ·dequant(pdata[s])``
-inside the existing inner loop — one extra gather+FMA per edge in all
-three designs, never a second aggregation pass.  ``gamma`` is a static
-(jit-cache-keyed) float; with ``pdata=None`` the emitted kernels are
-exactly the predictor-free ones.
+**Grids.**  One body (:func:`_accumulate`), two grids:
+
+  * **Chunked** (:func:`halo_spmm_stream_pallas`): grid = (row_blocks,
+    feature_blocks, table_chunks).  The chunk axis is innermost and the
+    output block index is chunk-invariant, so the accumulator tile stays
+    in VMEM while the Pallas pipeline double-buffers the HBM→VMEM DMA of
+    chunk c+1 behind the product of chunk c.  VMEM residency is O(chunk).
+    The **resident** kernel (:func:`halo_spmm_pallas`) is this grid with
+    one chunk spanning the whole (128-row padded) table: its block index
+    never changes, so the table is fetched once.
+  * **Chunk-skipping** (:func:`halo_spmm_skip_pallas`): grid = (row_blocks,
+    feature_blocks, ``max_chunks_per_block``); the table BlockSpec's index
+    map reads ``wl_ids[i, t]`` from a scalar-prefetch worklist
+    (:class:`repro.graph.partition.ChunkWorklist`), so row block i streams
+    only the chunks its edges reference.  Padded worklist entries repeat
+    the last visited chunk (re-addressed, no DMA) and are masked out
+    (``t >= cnt``), so the result is **bitwise identical** to the chunked
+    grid at the same ``chunk_rows``: a skipped chunk only ever contributed
+    an all-zero A tile.
+
+Every entry point takes ``interpret`` as a required keyword: the kernels
+run compiled on a TPU, and interpret mode (CPU, for tests) is asked for
+by name, never reached by default.
 """
 from __future__ import annotations
 
@@ -71,82 +67,63 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.spmm.spmm import BLOCK_F, BLOCK_ROWS, spmm_pallas
+# Row/feature tile of every aggregation kernel: MXU/VPU-aligned.
+BLOCK_ROWS = 128
+BLOCK_F = 128
 
-# Streaming-variant tile height: 512 fp32 rows × 128-wide stripe = 256 KiB
-# per buffer (×2 for the double buffer) — far under the 16 MiB VMEM budget
-# while long enough to amortize DMA issue latency.
+# Streaming tile height: a 512 × 128 fp32 chunk is 256 KiB per buffer (×2
+# for the double buffer), and its 128 × 512 fp32 A tile another 256 KiB —
+# far under the VMEM budget while long enough to amortize DMA issue.
 STREAM_CHUNK_ROWS = 512
 
 
-def _halo_kernel_scaled(nbr_ref, wts_ref, data_ref, scale_ref, out_ref):
-    deg = nbr_ref.shape[1]
-    table = data_ref[...]                        # (rows_tab, BF) int8
-    scale = scale_ref[...][:, 0]                 # (rows_tab,) fp32
+def _accumulate(base, nbr_ref, wts_ref, data_ref, scale_ref, pdata_ref,
+                pscale_ref, gamma: float, out_ref):
+    """Add one table chunk's contribution to the output tile.
 
-    def body(k, acc):
-        idx = nbr_ref[:, k]
-        gathered = jnp.take(table, idx, axis=0).astype(jnp.float32)
-        # Fold the per-row dequant scale into the edge weight: one FMA.
-        w = wts_ref[:, k].astype(jnp.float32) * jnp.take(scale, idx, axis=0)
-        return acc + w[:, None] * gathered
+    ``nbr_ref``/``wts_ref`` are the block's transposed ELL ``(deg, BR)``;
+    ``data_ref`` the ``(C, BF)`` chunk holding table rows
+    ``[base, base + C)``; ``scale_ref`` its ``(1, C)`` scales.  Edges whose
+    slot falls outside the chunk add exact zeros to A.  The single body
+    every grid runs, so their bitwise-equality invariants have one source
+    of truth."""
+    deg, br = nbr_ref.shape
+    chunk = data_ref.shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (chunk, br), 0) + base
 
-    acc = jnp.zeros(out_ref.shape, jnp.float32)
-    acc = jax.lax.fori_loop(0, deg, body, acc)
-    out_ref[...] = acc.astype(out_ref.dtype)
+    def body(k, a_t):
+        hit = slot == nbr_ref[pl.ds(k, 1), :]
+        w = wts_ref[pl.ds(k, 1), :].astype(jnp.float32)
+        return a_t + jnp.where(hit, w, 0.0)
 
+    a = jax.lax.fori_loop(0, deg, body,
+                          jnp.zeros((chunk, br), jnp.float32)).T
 
-def _make_resident_pred_kernel(gamma: float):
-    """Resident kernel with the SAT epilogue: each gathered row is
-    ``dequant(data[s]) + gamma * dequant(pdata[s])`` — the prediction
-    rides the same gather loop, one extra gather+FMA per edge."""
-    def kernel(nbr_ref, wts_ref, data_ref, scale_ref, pdata_ref,
-               pscale_ref, out_ref):
-        deg = nbr_ref.shape[1]
-        table = data_ref[...]
-        scale = scale_ref[...][:, 0]
-        ptable = pdata_ref[...]
-        pscale = pscale_ref[...][:, 0]
+    def product(a_scaled, tab):
+        return jnp.dot(a_scaled, tab.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
 
-        def body(k, acc):
-            idx = nbr_ref[:, k]
-            w = wts_ref[:, k].astype(jnp.float32)
-            gathered = jnp.take(table, idx, axis=0).astype(jnp.float32)
-            pgathered = jnp.take(ptable, idx, axis=0).astype(jnp.float32)
-            ws = w * jnp.take(scale, idx, axis=0)
-            wp = w * jnp.float32(gamma) * jnp.take(pscale, idx, axis=0)
-            return acc + ws[:, None] * gathered + wp[:, None] * pgathered
-
-        acc = jnp.zeros(out_ref.shape, jnp.float32)
-        acc = jax.lax.fori_loop(0, deg, body, acc)
-        out_ref[...] = acc.astype(out_ref.dtype)
-
-    return kernel
+    acc = product(a * scale_ref[...], data_ref[...])
+    if pdata_ref is not None:
+        acc = acc + product(a * (jnp.float32(gamma) * pscale_ref[...]),
+                            pdata_ref[...])
+    out_ref[...] += acc
 
 
-@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
-def halo_spmm_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
-                     scale: jax.Array = None, pdata: jax.Array = None,
-                     pscale: jax.Array = None, gamma: float = 1.0,
-                     interpret: bool = True) -> jax.Array:
-    """Fused pull+aggregate via pallas_call.
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel output's type.  Inside a ``shard_map`` it varies over every
+    mesh axis any operand varies over (the ``check_vma`` type check)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands
+                              if x is not None))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
-    Args:
-      nbr:   (rows, deg) int32 — compact-store slot ids (< data.shape[0]).
-      wts:   (rows, deg) float — 0 at padding slots.
-      data:  (n_slots_padded, feat) slab incl. sentinel row (fp32/bf16/int8).
-      scale: optional (n_slots_padded, 1) fp32 per-row dequant scales.
-      pdata/pscale: optional predictor-history slab in the same layout;
-        gathered rows become dequant(data) + gamma·dequant(pdata).
-      gamma: static extrapolation coefficient (jit-cache-keyed).
-    Returns:
-      (rows, feat) float32 result.
-    """
-    if scale is None and pdata is None:
-        # Unscaled fp32/bf16 slabs are exactly the ELL SpMM (its inner
-        # loop already upcasts gathered rows to f32); one kernel body to
-        # keep in sync for future block/DMA changes.
-        return spmm_pallas(nbr, wts, data, interpret=interpret)
+
+def _prepare(nbr, wts, data, scale, pdata, pscale, chunk_rows: int,
+             interpret: bool):
+    """Shared operand layout of every grid: transposed ELL, lane-dense
+    scale rows, table (and scales) padded to a whole number of chunks
+    (zero rows no index reaches)."""
     rows, deg = nbr.shape
     n_tab, feat = data.shape
     br = min(BLOCK_ROWS, rows)
@@ -154,83 +131,52 @@ def halo_spmm_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
     if rows % br or feat % bf:
         raise ValueError(f"rows={rows} feat={feat} must be divisible by "
                          f"block ({br},{bf}); pad upstream")
-    grid = (rows // br, feat // bf)
-    specs = [
-        pl.BlockSpec((br, deg), lambda i, j: (i, 0)),
-        pl.BlockSpec((br, deg), lambda i, j: (i, 0)),
-        pl.BlockSpec((n_tab, bf), lambda i, j: (0, j)),
-        pl.BlockSpec((n_tab, 1), lambda i, j: (0, 0)),
-    ]
-    if scale is None:
-        scale = jnp.ones((n_tab, 1), jnp.float32)
-    if pdata is None:
-        return pl.pallas_call(
-            _halo_kernel_scaled,
-            grid=grid,
-            in_specs=specs,
-            out_specs=pl.BlockSpec((br, bf), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
-            interpret=interpret,
-        )(nbr, wts, data, scale)
-    if pscale is None:
-        pscale = jnp.ones((n_tab, 1), jnp.float32)
-    specs += [
-        pl.BlockSpec((n_tab, bf), lambda i, j: (0, j)),
-        pl.BlockSpec((n_tab, 1), lambda i, j: (0, 0)),
-    ]
-    return pl.pallas_call(
-        _make_resident_pred_kernel(gamma),
-        grid=grid,
-        in_specs=specs,
-        out_specs=pl.BlockSpec((br, bf), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
-        interpret=interpret,
-    )(nbr, wts, data, scale, pdata, pscale)
+    if not interpret and chunk_rows % 128:
+        raise ValueError(f"chunk_rows={chunk_rows} must be a multiple of "
+                         f"128 for the compiled kernel (lane tile)")
+
+    def scale_row(s):
+        if s is None:
+            return jnp.ones((1, n_tab), jnp.float32)
+        return s.reshape(1, n_tab).astype(jnp.float32)
+
+    pad = (-n_tab) % chunk_rows
+    tables = [data, scale_row(scale)]
+    if pdata is not None:
+        tables += [pdata, scale_row(pscale)]
+    tables = [jnp.pad(t, ((0, pad), (0, 0)) if i % 2 == 0
+                      else ((0, 0), (0, pad)))
+              for i, t in enumerate(tables)]
+    return (nbr.T, wts.T, tables, br, bf, (n_tab + pad) // chunk_rows)
 
 
-def _chunk_contrib(base, chunk_rows: int, nbr_ref, wts_ref, data_ref,
-                   scale_ref, out_shape, pdata_ref=None, pscale_ref=None,
-                   gamma: float = 1.0):
-    """One chunk's masked gather/dequant/FMA partial sum — the single
-    inner loop both streamed kernels (dense and chunk-skipping) run, so
-    their bitwise-equality invariant has one source of truth.  Edges
-    whose slot falls outside [base, base + chunk_rows) contribute exact
-    ±0.0.  With a predictor tile (``pdata_ref``/``pscale_ref``) the
-    gathered row is the SAT prediction dequant(data) + γ·dequant(pdata)
-    — one extra gather+FMA inside the same loop, again for both streamed
-    kernels at once."""
-    deg = nbr_ref.shape[1]
-    table = data_ref[...]                        # (chunk_rows, BF) tile
-    scale = scale_ref[...][:, 0]                 # (chunk_rows,)
-    if pdata_ref is not None:
-        ptable = pdata_ref[...]
-        pscale = pscale_ref[...][:, 0]
+def _table_specs(chunk_rows: int, bf: int, pred: bool, chunk_of):
+    """BlockSpecs of (data, scale[, pdata, pscale]); ``chunk_of`` maps the
+    grid indices (and any scalar-prefetch refs) to the chunk id."""
+    def data_spec():
+        return pl.BlockSpec((chunk_rows, bf),
+                            lambda i, j, t, *p: (chunk_of(i, t, *p), j))
 
-    def body(k, acc):
-        idx = nbr_ref[:, k] - base
-        hit = (idx >= 0) & (idx < chunk_rows)
-        idx = jnp.where(hit, idx, 0)
-        gathered = jnp.take(table, idx, axis=0).astype(jnp.float32)
-        w = (wts_ref[:, k].astype(jnp.float32)
-             * jnp.take(scale, idx, axis=0)
-             * hit.astype(jnp.float32))
-        acc = acc + w[:, None] * gathered
-        if pdata_ref is not None:
-            pgathered = jnp.take(ptable, idx, axis=0).astype(jnp.float32)
-            wp = (wts_ref[:, k].astype(jnp.float32) * jnp.float32(gamma)
-                  * jnp.take(pscale, idx, axis=0)
-                  * hit.astype(jnp.float32))
-            acc = acc + wp[:, None] * pgathered
-        return acc
-
-    return jax.lax.fori_loop(0, deg, body,
-                             jnp.zeros(out_shape, jnp.float32))
+    def scale_spec():
+        return pl.BlockSpec((1, chunk_rows),
+                            lambda i, j, t, *p: (0, chunk_of(i, t, *p)))
+    specs = [data_spec(), scale_spec()]
+    if pred:
+        specs += [data_spec(), scale_spec()]
+    return specs
 
 
-def _make_stream_kernel(chunk_rows: int, pred: bool = False,
-                        gamma: float = 1.0):
-    def kernel(base_ref, nbr_ref, wts_ref, data_ref, scale_ref, *rest):
-        pdata_ref, pscale_ref = (rest[0], rest[1]) if pred else (None, None)
+def _chunked_call(nbr, wts, data, scale, pdata, pscale, gamma: float,
+                  chunk_rows: int, interpret: bool) -> jax.Array:
+    rows = nbr.shape[0]
+    feat = data.shape[1]
+    pred = pdata is not None
+    nbr_t, wts_t, tables, br, bf, n_chunks = _prepare(
+        nbr, wts, data, scale, pdata, pscale, chunk_rows, interpret)
+    deg = nbr.shape[1]
+
+    def kernel(nbr_ref, wts_ref, data_ref, scale_ref, *rest):
+        pdata_ref, pscale_ref = rest[:2] if pred else (None, None)
         out_ref = rest[-1]
         c = pl.program_id(2)
 
@@ -238,12 +184,51 @@ def _make_stream_kernel(chunk_rows: int, pred: bool = False,
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        out_ref[...] += _chunk_contrib(base_ref[c], chunk_rows, nbr_ref,
-                                       wts_ref, data_ref, scale_ref,
-                                       out_ref.shape, pdata_ref,
-                                       pscale_ref, gamma)
+        _accumulate(c * chunk_rows, nbr_ref, wts_ref, data_ref, scale_ref,
+                    pdata_ref, pscale_ref, gamma, out_ref)
 
-    return kernel
+    ell_spec = pl.BlockSpec((deg, br), lambda i, j, c: (0, i))
+    return pl.pallas_call(
+        kernel,
+        # Chunk axis innermost: the output block index is chunk-invariant,
+        # so the accumulator tile stays in VMEM while chunks stream past.
+        grid=(rows // br, feat // bf, n_chunks),
+        in_specs=[ell_spec, ell_spec] + _table_specs(
+            chunk_rows, bf, pred, lambda i, c: c),
+        out_specs=pl.BlockSpec((br, bf), lambda i, j, c: (i, j)),
+        out_shape=_out_struct((rows, feat), jnp.float32, nbr_t, wts_t,
+                              *tables),
+        interpret=interpret,
+    )(nbr_t, wts_t, *tables)
+
+
+def resident_rows(n_tab: int) -> int:
+    """Table rows the resident kernel carries: ``n_tab`` padded to a whole
+    128-row tile (the A tile's lane dimension)."""
+    return -(-n_tab // 128) * 128
+
+
+@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
+def halo_spmm_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
+                     scale: jax.Array = None, pdata: jax.Array = None,
+                     pscale: jax.Array = None, gamma: float = 1.0, *,
+                     interpret: bool) -> jax.Array:
+    """Resident aggregation: the whole table is one chunk in VMEM.
+
+    Args:
+      nbr:   (rows, deg) int32 — table row ids (< data.shape[0]).
+      wts:   (rows, deg) float — 0 at padding slots.
+      data:  (n_tab, feat) table incl. sentinel row (fp32/bf16/int8).
+      scale: optional (n_tab, 1) fp32 per-row dequant scales.
+      pdata/pscale: optional predictor-history table in the same layout;
+        rows read as dequant(data) + gamma·dequant(pdata).
+      gamma: static extrapolation coefficient (jit-cache-keyed).
+      interpret: run the kernel body in the Pallas interpreter (CPU).
+    Returns:
+      (rows, feat) float32 result.
+    """
+    return _chunked_call(nbr, wts, data, scale, pdata, pscale, gamma,
+                         resident_rows(data.shape[0]), interpret)
 
 
 @functools.partial(jax.jit,
@@ -252,112 +237,19 @@ def halo_spmm_stream_pallas(nbr: jax.Array, wts: jax.Array,
                             data: jax.Array, scale: jax.Array = None,
                             pdata: jax.Array = None,
                             pscale: jax.Array = None, gamma: float = 1.0,
-                            chunk_rows: int = STREAM_CHUNK_ROWS,
-                            interpret: bool = True) -> jax.Array:
-    """Streaming fused pull+aggregate: the slab never resides in VMEM.
+                            chunk_rows: int = STREAM_CHUNK_ROWS, *,
+                            interpret: bool) -> jax.Array:
+    """Streaming aggregation: the table never resides in VMEM whole.
 
-    Same contract as :func:`halo_spmm_pallas`, but the slab is tiled into
-    ``chunk_rows``-row chunks streamed through VMEM by the Pallas
-    pipeline (double-buffered HBM→VMEM DMA on TPU) while the output tile
-    accumulates in place.  Handles slabs far beyond the VMEM-resident
-    limit; fp32 accumulation is reassociated across chunks, so results
-    match the resident kernel within dtype tolerance (exactly for the
-    sub-sums inside one chunk).
+    Same contract as :func:`halo_spmm_pallas`, but the table is tiled into
+    ``chunk_rows``-row chunks streamed through VMEM by the Pallas pipeline
+    (double-buffered HBM→VMEM DMA on TPU) while the output tile
+    accumulates in place.  fp32 accumulation is reassociated across
+    chunks, so results match the resident kernel within dtype tolerance
+    (bitwise when one chunk spans the table).
     """
-    rows, deg = nbr.shape
-    n_tab, feat = data.shape
-    br = min(BLOCK_ROWS, rows)
-    bf = min(BLOCK_F, feat)
-    if rows % br or feat % bf:
-        raise ValueError(f"rows={rows} feat={feat} must be divisible by "
-                         f"block ({br},{bf}); pad upstream")
-    if scale is None:
-        scale = jnp.ones((n_tab, 1), jnp.float32)
-    pred = pdata is not None
-    if pred and pscale is None:
-        pscale = jnp.ones((n_tab, 1), jnp.float32)
-    # Pad the slab (and scales) to a whole number of chunks; padding rows
-    # are all-zero and no index ever reaches them.
-    pad = (-n_tab) % chunk_rows
-    if pad:
-        data = jnp.pad(data, ((0, pad), (0, 0)))
-        scale = jnp.pad(scale, ((0, pad), (0, 0)), constant_values=1.0)
-        if pred:
-            pdata = jnp.pad(pdata, ((0, pad), (0, 0)))
-            pscale = jnp.pad(pscale, ((0, pad), (0, 0)),
-                             constant_values=1.0)
-    n_chunks = (n_tab + pad) // chunk_rows
-    chunk_base = jnp.arange(n_chunks, dtype=jnp.int32) * chunk_rows
-
-    in_specs = [
-        pl.BlockSpec((br, deg), lambda i, j, c, b: (i, 0)),
-        pl.BlockSpec((br, deg), lambda i, j, c, b: (i, 0)),
-        pl.BlockSpec((chunk_rows, bf), lambda i, j, c, b: (c, j)),
-        pl.BlockSpec((chunk_rows, 1), lambda i, j, c, b: (c, 0)),
-    ]
-    operands = [chunk_base, nbr, wts, data, scale]
-    if pred:
-        # The history slab streams chunk-for-chunk beside the data slab.
-        in_specs += [
-            pl.BlockSpec((chunk_rows, bf), lambda i, j, c, b: (c, j)),
-            pl.BlockSpec((chunk_rows, 1), lambda i, j, c, b: (c, 0)),
-        ]
-        operands += [pdata, pscale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        # Chunk axis innermost: the output block index is chunk-invariant,
-        # so the accumulator tile stays in VMEM while slab chunks stream
-        # past it (the pipeline prefetches chunk c+1 during chunk c).
-        grid=(rows // br, feat // bf, n_chunks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, bf), lambda i, j, c, b: (i, j)),
-    )
-    return pl.pallas_call(
-        _make_stream_kernel(chunk_rows, pred, gamma),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
-        interpret=interpret,
-    )(*operands)
-
-
-def _make_skip_kernel(chunk_rows: int, count_visits: bool,
-                      pred: bool = False, gamma: float = 1.0):
-    def kernel(ids_ref, cnt_ref, nbr_ref, wts_ref, data_ref, scale_ref,
-               *rest):
-        pdata_ref, pscale_ref = (rest[0], rest[1]) if pred else (None, None)
-        out_refs = rest[2:] if pred else rest
-        out_ref = out_refs[0]
-        i = pl.program_id(0)
-        t = pl.program_id(2)
-
-        @pl.when(t == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        # Worklist lookup: this grid step carries slab chunk ids[i, t]
-        # (the data/scale BlockSpecs below used the same entry, so that
-        # chunk's tile is what sits in VMEM).  Entries at t >= cnt[i]
-        # repeat the previous chunk — already resident, no DMA — and are
-        # masked out of the accumulation here.
-        base = ids_ref[i, t] * chunk_rows
-        active = t < cnt_ref[i]
-
-        @pl.when(active)
-        def _accumulate():
-            out_ref[...] += _chunk_contrib(base, chunk_rows, nbr_ref,
-                                           wts_ref, data_ref, scale_ref,
-                                           out_ref.shape, pdata_ref,
-                                           pscale_ref, gamma)
-
-        if count_visits:
-            visit_ref = out_refs[1]
-
-            @pl.when(pl.program_id(1) == 0)
-            def _log():
-                visit_ref[0, 0] = jnp.where(active, ids_ref[i, t],
-                                            jnp.int32(-1))
-
-    return kernel
+    return _chunked_call(nbr, wts, data, scale, pdata, pscale, gamma,
+                         chunk_rows, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_rows", "gamma",
@@ -368,10 +260,10 @@ def halo_spmm_skip_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
                           wl_cnt: jax.Array = None,
                           pdata: jax.Array = None,
                           pscale: jax.Array = None, gamma: float = 1.0,
-                          chunk_rows: int = STREAM_CHUNK_ROWS,
-                          interpret: bool = True,
+                          chunk_rows: int = STREAM_CHUNK_ROWS, *,
+                          interpret: bool,
                           count_visits: bool = False):
-    """Chunk-skipping streamed pull+aggregate: occupancy-proportional DMA.
+    """Chunk-skipping streamed aggregation: occupancy-proportional work.
 
     Same contract as :func:`halo_spmm_stream_pallas`, plus a precomputed
     worklist (``repro.graph.partition.build_chunk_worklist`` with the
@@ -382,25 +274,20 @@ def halo_spmm_skip_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
       wl_cnt: (row_blocks,) int32 — valid prefix length per block.
 
     The innermost grid dimension runs over the *worklist position* t, and
-    the slab BlockSpec resolves it to chunk ``wl_ids[i, t]`` via scalar
-    prefetch — so the pipeline DMAs exactly the occupied chunks (padded
-    steps re-address the resident block) while keeping the streaming
-    kernel's double-buffered overlap and in-VMEM accumulator.  Bitwise
-    equal to the dense stream at the same ``chunk_rows``.
+    the table BlockSpec resolves it to chunk ``wl_ids[i, t]`` via scalar
+    prefetch — so the pipeline DMAs, and the VPU builds A tiles for,
+    exactly the occupied chunks.  Bitwise equal to the dense stream at the
+    same ``chunk_rows``.
 
     With ``count_visits=True`` a second output (row_blocks, max_chunks)
     int32 records the chunk id processed at each (block, t) — ``-1`` at
     masked padding steps — so tests can assert visited chunks ==
-    worklist entries.  Debug/interpret-mode only: the (1, 1) block shape
-    is not a legal TPU tile.
+    worklist entries.  Interpret mode only: the (1, 1) block shape is not
+    a legal TPU tile.
     """
     rows, deg = nbr.shape
     n_tab, feat = data.shape
     br = min(BLOCK_ROWS, rows)
-    bf = min(BLOCK_F, feat)
-    if rows % br or feat % bf:
-        raise ValueError(f"rows={rows} feat={feat} must be divisible by "
-                         f"block ({br},{bf}); pad upstream")
     if wl_ids is None or wl_cnt is None:
         raise ValueError("halo_spmm_skip_pallas needs the (wl_ids, wl_cnt)"
                          " worklist; build it with "
@@ -414,7 +301,7 @@ def halo_spmm_skip_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
             f"rebuild the worklist with block_rows={br}")
     if max_chunks > n_chunks:
         # A well-formed worklist never lists more distinct chunks than
-        # the slab tiling has — a wider one means it was built with a
+        # the table tiling has — a wider one means it was built with a
         # smaller chunk_rows than this call's.  (The converse mismatch —
         # a coarser worklist — is undetectable from the traced arrays;
         # keep the build chunk_rows and the call chunk_rows wired to the
@@ -424,61 +311,65 @@ def halo_spmm_skip_pallas(nbr: jax.Array, wts: jax.Array, data: jax.Array,
             f"{max_chunks} chunks per block but a {n_tab}-row slab at "
             f"chunk_rows={chunk_rows} has only {n_chunks} — rebuild the "
             f"worklist with this chunk_rows")
-    if scale is None:
-        scale = jnp.ones((n_tab, 1), jnp.float32)
     pred = pdata is not None
-    if pred and pscale is None:
-        pscale = jnp.ones((n_tab, 1), jnp.float32)
-    pad = (-n_tab) % chunk_rows
-    if pad:
-        data = jnp.pad(data, ((0, pad), (0, 0)))
-        scale = jnp.pad(scale, ((0, pad), (0, 0)), constant_values=1.0)
-        if pred:
-            pdata = jnp.pad(pdata, ((0, pad), (0, 0)))
-            pscale = jnp.pad(pscale, ((0, pad), (0, 0)),
-                             constant_values=1.0)
+    nbr_t, wts_t, tables, br, bf, _ = _prepare(
+        nbr, wts, data, scale, pdata, pscale, chunk_rows, interpret)
 
-    out_shape = [jax.ShapeDtypeStruct((rows, feat), jnp.float32)]
+    def kernel(ids_ref, cnt_ref, nbr_ref, wts_ref, data_ref, scale_ref,
+               *rest):
+        pdata_ref, pscale_ref = rest[:2] if pred else (None, None)
+        out_refs = rest[2:] if pred else rest
+        out_ref = out_refs[0]
+        i = pl.program_id(0)
+        t = pl.program_id(2)
+
+        @pl.when(t == 0)
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        # Worklist lookup: this grid step carries table chunk ids[i, t]
+        # (the table BlockSpecs used the same entry, so that chunk's tile
+        # is what sits in VMEM).  Entries at t >= cnt[i] repeat the
+        # previous chunk — already resident, no DMA — and are masked out.
+        active = t < cnt_ref[i]
+
+        @pl.when(active)
+        def _acc():
+            _accumulate(ids_ref[i, t] * chunk_rows, nbr_ref, wts_ref,
+                        data_ref, scale_ref, pdata_ref, pscale_ref, gamma,
+                        out_ref)
+
+        if count_visits:
+            visit_ref = out_refs[1]
+
+            @pl.when(pl.program_id(1) == 0)
+            def _log():
+                visit_ref[0, 0] = jnp.where(active, ids_ref[i, t],
+                                            jnp.int32(-1))
+
+    operands = (wl_ids, wl_cnt, nbr_t, wts_t, *tables)
+    out_shape = [_out_struct((rows, feat), jnp.float32, *operands)]
     out_specs = [pl.BlockSpec((br, bf), lambda i, j, t, ids, cnt: (i, j))]
     if count_visits:
-        out_shape.append(jax.ShapeDtypeStruct((n_blocks, max_chunks),
-                                              jnp.int32))
+        out_shape.append(_out_struct((n_blocks, max_chunks), jnp.int32,
+                                     *operands))
         out_specs.append(pl.BlockSpec((1, 1),
                                       lambda i, j, t, ids, cnt: (i, t)))
-
-    in_specs = [
-        pl.BlockSpec((br, deg), lambda i, j, t, ids, cnt: (i, 0)),
-        pl.BlockSpec((br, deg), lambda i, j, t, ids, cnt: (i, 0)),
-        pl.BlockSpec((chunk_rows, bf),
-                     lambda i, j, t, ids, cnt: (ids[i, t], j)),
-        pl.BlockSpec((chunk_rows, 1),
-                     lambda i, j, t, ids, cnt: (ids[i, t], 0)),
-    ]
-    operands = [wl_ids, wl_cnt, nbr, wts, data, scale]
-    if pred:
-        # History slab tiles resolve through the same worklist entry, so
-        # skipped chunks stay skipped with the predictor on.
-        in_specs += [
-            pl.BlockSpec((chunk_rows, bf),
-                         lambda i, j, t, ids, cnt: (ids[i, t], j)),
-            pl.BlockSpec((chunk_rows, 1),
-                         lambda i, j, t, ids, cnt: (ids[i, t], 0)),
-        ]
-        operands += [pdata, pscale]
+    ell_spec = pl.BlockSpec((deg, br), lambda i, j, t, ids, cnt: (0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # Worklist position innermost: the output block index is
-        # t-invariant (accumulator stays in VMEM) and the slab BlockSpec
+        # t-invariant (accumulator stays in VMEM) and the table BlockSpec
         # resolves t through the prefetched worklist, so the pipeline
         # prefetches chunk ids[i, t+1] during chunk ids[i, t].
         grid=(rows // br, feat // bf, max_chunks),
-        in_specs=in_specs,
+        in_specs=[ell_spec, ell_spec] + _table_specs(
+            chunk_rows, bf, pred, lambda i, t, ids, cnt: ids[i, t]),
         out_specs=out_specs if count_visits else out_specs[0],
     )
-    out = pl.pallas_call(
-        _make_skip_kernel(chunk_rows, count_visits, pred, gamma),
+    return pl.pallas_call(
+        kernel,
         grid_spec=grid_spec,
         out_shape=out_shape if count_visits else out_shape[0],
         interpret=interpret,
     )(*operands)
-    return out

@@ -4,7 +4,9 @@ allocation), and extract the roofline terms.
 
 MUST be run as its own process (`python -m repro.launch.dryrun ...`) — the
 XLA_FLAGS assignment below executes before any jax import so jax sees 512
-placeholder devices. Do NOT import this module from tests/benches.
+placeholder devices. Do NOT import this module from tests/benches.  It
+pins the CPU platform itself, so on a machine with a TPU attached it
+never takes the chip.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k
@@ -13,6 +15,7 @@ Usage:
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json
@@ -44,12 +47,8 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
 
 def cost_properties(compiled) -> dict:
-    """jax-version compat: ``Compiled.cost_analysis()`` returns a dict on
-    jax ≥ 0.5 but a one-element list of dicts on 0.4.x jaxlib."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()`` as a dict (empty where XLA has none)."""
+    return compiled.cost_analysis() or {}
 
 
 def _groups_cross_pod(line: str, pod_boundary: int) -> bool:

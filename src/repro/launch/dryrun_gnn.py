@@ -14,10 +14,12 @@ script's census output).
   PYTHONPATH=src python -m repro.launch.dryrun_gnn --multi-pod \\
       --pull collective --parts-per-device 2
 
-Run as its own process (512 placeholder devices).
+Run as its own process (512 placeholder devices on the CPU platform,
+which it pins itself, so it never takes an attached TPU).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json
@@ -29,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (HaloPrecision, PredictorConfig, TrainSettings,
                         make_epoch_fn)
+from repro.kernels.spmm import BACKENDS
 from repro.launch.dryrun import collective_bytes, cost_properties
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS,
                                make_production_mesh)
@@ -126,13 +129,12 @@ def main():
     ap.add_argument("--parts-per-device", type=int, default=1,
                     help="k subgraphs/owner shards per 'data' device "
                          "(M = k x data axis; the M > pod-size regime)")
-    ap.add_argument("--backend", default="jnp",
-                    choices=("jnp", "auto", "pallas"),
+    ap.add_argument("--backend", default="auto", choices=BACKENDS,
                     help="aggregation kernel backend the epoch lowers "
                          "with; the forced-host-device dry run compiles "
-                         "for CPU, so only 'jnp' lowers here — 'auto'/"
-                         "'pallas' are for running this script on a real "
-                         "TPU pod, where the knobs below select kernels")
+                         "for CPU, where 'auto' resolves to the jnp "
+                         "reference — the Pallas kernels' own compile "
+                         "check is tests/test_tpu_compile.py")
     ap.add_argument("--stream-chunk-rows", type=int, default=512,
                     help="slab rows per streamed halo_spmm chunk (also "
                          "the abstract worklist geometry)")

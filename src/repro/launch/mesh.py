@@ -6,18 +6,12 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-import jax.sharding
-
-# jax < 0.5 has no jax.sharding.AxisType (and make_mesh takes no axis_types
-# kwarg); fall back to plain meshes there so imports stay version-portable.
-AxisType = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = None):
@@ -38,15 +32,18 @@ def make_production_mesh(*, multi_pod: bool = False, pods: int = None):
     return _make_mesh((16, 16), ("data", "model"))
 
 
-def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
-    """Small mesh over however many (host) devices exist — tests/examples."""
+def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,
+                   devices=None):
+    """Small mesh over however many (host) devices exist — tests/examples
+    — or over ``devices`` (e.g. ``jax.devices()[:1]``, a one-device
+    reference beside a larger mesh in the same process)."""
     shape, axes = [], []
     if pod > 1:
         shape.append(pod)
         axes.append("pod")
     shape += [data, model]
     axes += ["data", "model"]
-    return _make_mesh(tuple(shape), tuple(axes))
+    return _make_mesh(tuple(shape), tuple(axes), devices)
 
 
 # Hardware constants (TPU v5e) for the roofline report.

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import serving
 from repro.core.digest import prepare_graph_data, top_layer_reps
 from repro.graph import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.serving_driver import run_serve_loop
 from repro.models.gnn import GNNConfig, gnn_specs
@@ -58,6 +59,7 @@ def main():
     ap.add_argument("--sharded", action="store_true",
                     help="also time the SPMD engine over the host mesh")
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = make_dataset(args.dataset, scale=args.scale, seed=0)
     data = prepare_graph_data(g, args.parts, seed=0)

@@ -20,6 +20,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import HaloPrecision, HaloSpec, TrainSettings, evaluate, \
     init_state, make_epoch_fn, prepare_graph_data
 from repro.graph import make_dataset
+from repro.kernels.spmm import BACKENDS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.gnn import GNNConfig
 from repro.optim import adam
@@ -92,6 +94,34 @@ def subgraph_shardings(data: dict, state: dict, mesh) -> tuple[dict, dict]:
         state_sh["push_ok"] = m_shard
         state_sh["last_push_round"] = m_shard
     return data_sh, state_sh
+
+
+def model_config(g, data: dict, *, model: str, hidden_dim: int,
+                 num_layers: int = 3, **knobs) -> GNNConfig:
+    """The GNNConfig for graph ``g`` partitioned into ``data``: its
+    feature and class widths, and the halo worklist's measured occupancy
+    (which selects the chunk-skipping kernel).  ``knobs`` are the other
+    GNNConfig fields (backend, streaming overrides)."""
+    return GNNConfig(model=model, num_layers=num_layers,
+                     in_dim=g.features.shape[1], hidden_dim=hidden_dim,
+                     num_classes=int(g.labels.max()) + 1,
+                     halo_occupancy=data["_worklist"].occupancy, **knobs)
+
+
+def jit_epoch(cfg: GNNConfig, opt, settings: TrainSettings, data: dict,
+              state: dict, mesh):
+    """The full-batch DIGEST epoch, jitted over ``mesh`` with every
+    input's sharding (:func:`subgraph_shardings`).  Returns ``(epoch_fn,
+    state, tdata)``, the call ``epoch_fn(state, tdata)`` takes: ``tdata``
+    is ``data`` without its host-only ``_`` keys, and both are placed on
+    their shardings, so the first epoch's input types are the ones every
+    later epoch passes back (one compile, not two)."""
+    tdata = {k: v for k, v in data.items() if not k.startswith("_")}
+    data_sh, state_sh = subgraph_shardings(tdata, state, mesh)
+    epoch_fn = jax.jit(make_epoch_fn(cfg, opt, settings, mesh=mesh),
+                       in_shardings=(state_sh, data_sh))
+    return (epoch_fn, jax.device_put(state, state_sh),
+            jax.device_put(tdata, data_sh))
 
 
 def batch_shardings(mesh) -> dict:
@@ -183,13 +213,15 @@ def main():
                          "re-laid to match) so 128-row blocks reference "
                          "clustered slab chunks — lower worklist "
                          "occupancy, same math (pure row permutation)")
-    ap.add_argument("--backend", default="jnp",
-                    choices=("jnp", "auto", "pallas"),
-                    help="aggregation kernel backend: 'jnp' reference "
-                         "(CPU default), 'auto' picks the Pallas kernels "
-                         "on TPU hosts, 'pallas' forces them — the "
-                         "streaming/skip knobs below act on the Pallas "
-                         "paths (the jnp oracle has no DMA to schedule)")
+    ap.add_argument("--backend", default="auto", choices=BACKENDS,
+                    help="aggregation kernel backend: 'auto' runs the "
+                         "compiled Pallas kernels on a TPU and the jnp "
+                         "reference elsewhere; 'jnp' forces the "
+                         "reference, 'pallas*' a kernel, '*_interpret' "
+                         "the kernel bodies in the Pallas interpreter — "
+                         "the streaming/skip knobs below act on the "
+                         "Pallas paths (the jnp oracle has no DMA to "
+                         "schedule)")
     ap.add_argument("--stream-chunk-rows", type=int, default=None,
                     help="slab rows per streamed halo_spmm chunk "
                          "(default: kernel STREAM_CHUNK_ROWS; also sets "
@@ -269,6 +301,7 @@ def main():
     args = ap.parse_args()
     if args.resume and not args.ckpt_dir:
         ap.error("--resume needs --ckpt-dir")
+    enable_compile_cache()
 
     g = make_dataset(args.dataset, scale=args.scale)
     t_part = time.perf_counter()
@@ -279,15 +312,12 @@ def main():
     print(f"partition: {args.parts} parts, order={args.order}, "
           f"halo_weight={args.halo_weight} built in {t_part:.2f}s "
           f"({g.num_nodes} nodes, {len(g.indices) // 2} edges)")
-    cfg = GNNConfig(model=args.model, num_layers=3,
-                    in_dim=g.features.shape[1], hidden_dim=64,
-                    num_classes=int(g.labels.max()) + 1,
-                    backend=args.backend,
-                    stream_chunk_rows=args.stream_chunk_rows,
-                    resident_max_bytes=args.resident_max_bytes,
-                    skip_occupancy_max=args.skip_occupancy_max,
-                    halo_occupancy=data["_worklist"].occupancy,
-                    gat_halo_dedup=not args.no_gat_dedup)
+    cfg = model_config(g, data, model=args.model, hidden_dim=64,
+                       backend=args.backend,
+                       stream_chunk_rows=args.stream_chunk_rows,
+                       resident_max_bytes=args.resident_max_bytes,
+                       skip_occupancy_max=args.skip_occupancy_max,
+                       gat_halo_dedup=not args.no_gat_dedup)
     opt = adam(5e-3)
     from repro.core import PredictorConfig
     predictor = PredictorConfig(kind=args.predictor,
@@ -357,7 +387,7 @@ def main():
                      for k, v in sampler.sample(t).items()}
             state, m = step_fn(state, tdata, batch)
             _maybe_ckpt(args, t + 1, state)
-        ev = evaluate(cfg, state["params"], tdata)
+        ev = evaluate(cfg, state["params"], tdata, mesh)
     else:
         state = init_state(cfg, opt, data, precision=settings.precision,
                            predictor=settings.predictor)
@@ -366,9 +396,8 @@ def main():
         start = _maybe_resume(args, state)
         if start:
             state, _ = _restore(args, state)
-        data_sh, state_sh = subgraph_shardings(tdata, state, mesh)
-        epoch_fn = jax.jit(make_epoch_fn(cfg, opt, settings, mesh=mesh),
-                           in_shardings=(state_sh, data_sh))
+        epoch_fn, state, tdata = jit_epoch(cfg, opt, settings, data,
+                                           state, mesh)
         t0 = time.perf_counter()
         m = {"loss": float("nan")}
         for e in range(start, args.epochs):
@@ -376,7 +405,7 @@ def main():
                 state["push_ok"] = _push_ok(schedule, e + 1, args.parts)
             state, m = epoch_fn(state, tdata)
             _maybe_ckpt(args, e + 1, state)
-        ev = evaluate(cfg, state["params"], tdata)
+        ev = evaluate(cfg, state["params"], tdata, mesh)
     if fault_aware and "last_push_round" in state:
         import numpy as np
         age = int(state["epoch"]) - np.asarray(state["last_push_round"])

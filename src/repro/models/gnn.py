@@ -120,7 +120,9 @@ class GNNConfig:
     heads: int = 4                # GAT only
     normalize: bool = True        # Algorithm 1 line 11 (L2 per node)
     residual: bool = False
-    backend: str = "jnp"          # aggregation backend (jnp | pallas*)
+    # Aggregation backend (repro.kernels.spmm.BACKENDS): "auto" runs the
+    # compiled Pallas kernels on a TPU and the jnp reference elsewhere.
+    backend: str = "auto"
     # -- streamed halo_spmm knobs (static; override the module constants
     # of repro.kernels.spmm.ops — None keeps the kernel defaults) -------
     stream_chunk_rows: Optional[int] = None    # STREAM_CHUNK_ROWS
@@ -129,8 +131,8 @@ class GNNConfig:
     # Measured (row_block × chunk) occupancy of the partition's chunk
     # worklist (ChunkWorklist.occupancy) — a host-side float the launcher
     # copies in after building the data; drives skip-vs-dense stream
-    # auto-selection.  None disables the skip stream under backend="auto"
-    # selection (forced "pallas_skip*" backends still work).
+    # auto-selection.  None disables the skip stream under the ladder's
+    # own selection (forced "pallas_skip*" backends still work).
     halo_occupancy: Optional[float] = None
     # GAT: project each owner shard's stale halo rows once per layer at
     # pull time and ship projected rows (True, the dedup path) instead of
@@ -412,7 +414,9 @@ def gnn_forward(cfg: GNNConfig, params: Pytree, x_local: jax.Array,
     push: list[jax.Array] = []
     for ell in range(cfg.num_layers):
         p = params[f"layer_{ell}"]
-        out = layer_fn(cfg, p, h, halo_tables[ell], struct)
+        # The scope names the layer in the compiled program's op metadata.
+        with jax.named_scope(f"layer_{ell}"):
+            out = layer_fn(cfg, p, h, halo_tables[ell], struct)
         h = _finish_layer(cfg, out, h, ell, push)
     return h, push
 

@@ -29,6 +29,33 @@ from repro.launch.hlo_census import (COLLECTIVES, match_collective,
                                      op_groups)
 
 
+# Single-device vs SPMD-partitioned epochs: the partitioner re-tiles and
+# re-fuses the forward's reductions, so pushed representations agree to a
+# few float32 ULPs (of the array's largest magnitude), not bitwise; the
+# routing itself is exact (the KVS parity checks compare it bitwise).
+PARTITIONED_ULPS = 8
+
+
+def tree_close(a: dict, b: dict, what: str = ""):
+    """``a`` equals ``b`` up to the partitioner's reassociation: floats
+    within ``PARTITIONED_ULPS`` ULPs of ``max|b|``, int8 codes within one
+    quantization step (a reassociated row may round the other way)."""
+    import numpy as np
+
+    assert sorted(a) == sorted(b)
+    for k in a:
+        x, y = np.asarray(a[k]), np.asarray(b[k])
+        if np.issubdtype(y.dtype, np.integer):
+            diff = np.abs(x.astype(np.int32) - y.astype(np.int32))
+            assert diff.max(initial=0) <= 1, f"{what}[{k}]"
+            continue
+        x, y = x.astype(np.float32), y.astype(np.float32)
+        tol = (PARTITIONED_ULPS * np.finfo(np.float32).eps
+               * float(np.abs(y).max(initial=0)))
+        np.testing.assert_allclose(x, y, rtol=0, atol=tol,
+                                   err_msg=f"{what}[{k}]")
+
+
 def run_forced_device_subprocess(test_file: str, marker: str,
                                  devices: int = 8, timeout: int = 900):
     """Re-launch ``test_file`` as ``__main__`` with a forced N-device CPU

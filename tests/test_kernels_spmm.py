@@ -7,8 +7,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import halo_exchange as hx
-from repro.kernels.spmm import (halo_spmm, halo_spmm_ref,
-                                halo_spmm_stream_pallas, spmm, spmm_ref)
+from repro.kernels.spmm import (BACKENDS, halo_spmm, halo_spmm_ref,
+                                halo_spmm_stream_pallas, resolve_backend,
+                                spmm, spmm_ref)
 
 
 def _case(rng, rows, deg, ncols, feat, dtype):
@@ -149,3 +150,22 @@ def test_spmm_dense_oracle():
         for kk in range(sp.in_nbr.shape[-1]):
             P[i, sp.in_nbr[m, i, kk]] += sp.in_wts[m, i, kk]
     np.testing.assert_allclose(out, P @ x, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_backend_names_resolve(backend):
+    """"auto" is the compiled kernel only on a TPU (the jnp reference on
+    this CPU); every other documented name is taken as given."""
+    want = "jnp" if backend == "auto" else backend
+    assert resolve_backend(backend) == want
+
+
+@pytest.mark.parametrize("backend", ["interpret", "pallas-interpret",
+                                     "Pallas", ""])
+def test_unknown_backend_raises(backend):
+    with pytest.raises(ValueError, match="unknown aggregation backend"):
+        resolve_backend(backend)
+    nbr, wts, table = _case(np.random.default_rng(0), 8, 2, 5, 4,
+                            np.float32)
+    with pytest.raises(ValueError, match="unknown aggregation backend"):
+        spmm(nbr, wts, table, backend=backend)

@@ -97,3 +97,49 @@ def test_multi_device_lowering_subprocess():
     assert out.returncode == 0, out.stderr[-2000:]
     payload = json.loads(out.stdout.strip().splitlines()[-1])
     assert payload["ok"] and payload["flops"] > 0
+
+
+PER_SUBGRAPH_TEST = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core.halo_exchange import on_every_device, per_subgraph
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(data=4)
+    w = jnp.asarray([0.5, -2.0, 3.0])
+    x = jnp.arange(24.0).reshape(8, 3) / 7.0
+
+    def loss(w, x):
+        return jnp.sum(jnp.sin(w * x))
+
+    vg = jax.value_and_grad(loss)
+    want = jax.vmap(vg, in_axes=(None, 0))(w, x)
+    for check in (True, False):
+        got = jax.jit(per_subgraph(vg, mesh, (None, 0),
+                                   check_vma=check))(w, x)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-6)
+        whole = jax.grad(lambda w: jnp.sum(on_every_device(
+            lambda w, x: jnp.sin(w * x), mesh, check_vma=check)(w, x)))
+        np.testing.assert_allclose(jax.jit(whole)(w),
+                                   jax.grad(lambda w: jnp.sum(
+                                       jnp.sin(w * x)))(w), rtol=1e-6)
+    print("PER_SUBGRAPH_OK")
+""")
+
+
+def test_per_subgraph_gradients_subprocess():
+    """On a 4-device mesh, a gradient taken per subgraph inside
+    ``per_subgraph`` is that subgraph's own (not summed over devices),
+    and a gradient through ``on_every_device`` is the single-device one,
+    with the varying-axes check on and off."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    out = subprocess.run([sys.executable, "-c", PER_SUBGRAPH_TEST], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "PER_SUBGRAPH_OK" in out.stdout
