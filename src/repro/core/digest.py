@@ -70,6 +70,15 @@ Pytree = Any
 
 MODES = ("digest", "partition", "propagation")
 
+# Named scopes of the epoch's parts (Algorithm 1): they name every device
+# op in the compiled program's op metadata, so a profile of training reads
+# the inputs, the pull, the loss (forward and backward, with each layer's
+# ``aggregate``/``transform``/``attention`` scopes of repro.models.gnn
+# inside it), the optimizer, the push and the metrics apart.  The pull's,
+# push's, optimizer's and metrics' scopes sit by their functions below.
+INPUTS_SCOPE = "digest/inputs"      # round number, layer-0 features
+LOSS_SCOPE = "digest/loss"          # per-subgraph value_and_grad
+
 
 def gat_projected(cfg: GNNConfig) -> bool:
     """True when the epoch runs GAT with the owner-shard projection dedup:
@@ -378,6 +387,10 @@ class TrainSettings:
     predictor: PredictorConfig = PredictorConfig()
 
 
+PULL_SCOPE = "digest/pull"
+
+
+@jax.named_scope(PULL_SCOPE)
 def _digest_pull(cfg: GNNConfig, settings: TrainSettings, state: dict,
                  data: dict, mesh, r) -> dict:
     """Algorithm-1 PULL (line 5): gather each subgraph's halo slots from
@@ -431,6 +444,10 @@ def _digest_pull(cfg: GNNConfig, settings: TrainSettings, state: dict,
                         lambda: (state["cache"], state.get("pcache")))
 
 
+PUSH_SCOPE = "digest/push"
+
+
+@jax.named_scope(PUSH_SCOPE)
 def _digest_push(cfg: GNNConfig, settings: TrainSettings, state: dict,
                  data: dict, push_reps, mesh, r) -> tuple:
     """Periodic PUSH (Algorithm 1 lines 9–10; epochs r = 1, N+1, 2N+1,
@@ -488,19 +505,9 @@ def _digest_push(cfg: GNNConfig, settings: TrainSettings, state: dict,
             new_last = jnp.where(ok, jnp.asarray(r, new_last.dtype),
                                  new_last)
         pred = settings.predictor.enabled and new_pstore is not None
-        eps_store = state["store"]
-        if pred:
-            eps_store = {"data": (
-                halo_exchange.dequantize_rows(
-                    state["store"]["data"], state["store"].get("scale"))
-                + jnp.float32(settings.predictor.gamma)
-                * halo_exchange.dequantize_rows(
-                    state["pstore"]["data"], state["pstore"].get("scale")))}
+        eps = _staleness(settings, state, data, push_reps, pred,
+                         shard_rows, mesh)
         if settings.pull_mode == "collective":
-            eps = halo_exchange.shard_staleness_error(
-                eps_store, push_reps, data["local_slots"],
-                data["local_boundary"], shard_rows, mesh)
-
             def _push():
                 return halo_exchange.shard_push(
                     state["store"], data["local_slots"],
@@ -512,10 +519,6 @@ def _digest_push(cfg: GNNConfig, settings: TrainSettings, state: dict,
                     local_valid, push_reps,
                     state["push_residual"], shard_rows, mesh)
         else:
-            eps = halo_exchange.staleness_error(
-                eps_store, push_reps, data["local_slots"],
-                data["local_boundary"])
-
             def _push():
                 return halo_exchange.push(
                     state["store"], data["local_slots"],
@@ -561,6 +564,31 @@ def _digest_push(cfg: GNNConfig, settings: TrainSettings, state: dict,
     return new_store, new_residual, eps, new_last, new_pstore, new_hist
 
 
+STALENESS_SCOPE = "staleness"       # nested in PUSH_SCOPE
+
+
+@jax.named_scope(STALENESS_SCOPE)
+def _staleness(settings: TrainSettings, state: dict, data: dict, push_reps,
+               pred: bool, shard_rows: int, mesh) -> jax.Array:
+    """The Theorem-1 probe: eps per hidden layer, measured against the
+    rows consumers read (the store, or with the SAT predictor the virtual
+    fp32 store ``dequant(store) + γ·dequant(pstore)``)."""
+    eps_store = state["store"]
+    if pred:
+        eps_store = {"data": (
+            halo_exchange.dequantize_rows(
+                state["store"]["data"], state["store"].get("scale"))
+            + jnp.float32(settings.predictor.gamma)
+            * halo_exchange.dequantize_rows(
+                state["pstore"]["data"], state["pstore"].get("scale")))}
+    if settings.pull_mode == "collective":
+        return halo_exchange.shard_staleness_error(
+            eps_store, push_reps, data["local_slots"],
+            data["local_boundary"], shard_rows, mesh)
+    return halo_exchange.staleness_error(
+        eps_store, push_reps, data["local_slots"], data["local_boundary"])
+
+
 def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
                   mesh=None) -> Callable:
     if settings.mode not in MODES:
@@ -575,7 +603,8 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
     loss_fn = make_subgraph_loss(cfg)
 
     def epoch_fn(state: dict, data: dict) -> tuple[dict, dict]:
-        r = state["epoch"] + 1            # 1-indexed, as in Algorithm 1
+        with jax.named_scope(INPUTS_SCOPE):
+            r = state["epoch"] + 1        # 1-indexed, as in Algorithm 1
         x_global = data["x_global"]                         # (N+1, d)
         struct = data["struct"]
         halo_size = data["halo_ids"].shape[1]
@@ -586,9 +615,10 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
         # zero init), NOT the ELL weights — GAT's attention denominator
         # and SAGE's mean still see the dropped neighbors as zero
         # vectors, matching the seed semantics exactly.
-        x_halo0 = x_global[data["halo_ids_x"]]              # (M, H+1, d)
-        if settings.mode == "partition":
-            x_halo0 = jnp.zeros_like(x_halo0)
+        with jax.named_scope(INPUTS_SCOPE):
+            x_halo0 = x_global[data["halo_ids_x"]]          # (M, H+1, d)
+            if settings.mode == "partition":
+                x_halo0 = jnp.zeros_like(x_halo0)
 
         # GAT owner-shard dedup: the cache holds *projected* rows
         # (z{ell} = W·h̃, projected once per owner shard per layer at
@@ -641,7 +671,8 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
             cache = state["cache"]
             pcache = None
 
-        x_local = x_global[data["local_ids"]]               # (M, S, d)
+        with jax.named_scope(INPUTS_SCOPE):
+            x_local = x_global[data["local_ids"]]           # (M, S, d)
         n_hidden = cfg.num_layers - 1
         pred_tables = pcache is not None
 
@@ -683,14 +714,11 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
             jax.value_and_grad(sub_loss, has_aux=True), mesh,
             in_axes=(None, 0, 0, 0, 0, 0, 0, 0), what="per-subgraph loss",
             check_vma=vma_checkable(cfg.backend))
-        (losses, (push_reps, logits)), grads = vg(
-            state["params"], x_local, x_halo0, cache, pcache, struct,
-            data["labels"], data["train_mask"])
-
-        # Global AGG (Algorithm 1 line 13): uniform average over subgraphs.
-        mean_grads = jax.tree.map(lambda g: jnp.mean(g, axis=0), grads)
-        params, opt_state = opt.update(mean_grads, state["opt_state"],
-                                       state["params"], state["step"])
+        with jax.named_scope(LOSS_SCOPE):
+            (losses, (push_reps, logits)), grads = vg(
+                state["params"], x_local, x_halo0, cache, pcache, struct,
+                data["labels"], data["train_mask"])
+        params, opt_state, step = _opt_step(opt, state, grads)
 
         if settings.llcg_correction:
             # LLCG server correction: full-neighbor gradient on a sampled
@@ -716,11 +744,11 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
          new_hist) = _digest_push(cfg, settings, state, data, push_reps,
                                   mesh, r)
 
-        train_acc = micro_f1(logits, data["labels"],
-                             data["train_mask"].astype(jnp.float32))
+        metrics = _epoch_metrics(losses, logits, data["labels"],
+                                 data["train_mask"], eps, new_last, r)
         new_state = {"params": params, "opt_state": opt_state,
                      "store": new_store, "cache": cache,
-                     "epoch": r, "step": state["step"] + 1}
+                     "epoch": r, "step": step}
         if new_residual is not None:
             new_state["push_residual"] = new_residual
         if new_pstore is not None:
@@ -728,15 +756,42 @@ def make_epoch_fn(cfg: GNNConfig, opt: Optimizer, settings: TrainSettings,
             new_state["predictor"] = new_hist
         if pcache is not None:
             new_state["pcache"] = pcache
-        metrics = {"loss": jnp.mean(losses), "train_f1": train_acc,
-                   "staleness_eps": eps}
         if new_last is not None:
             new_state["push_ok"] = state["push_ok"]
             new_state["last_push_round"] = new_last
-            metrics["push_age"] = faults_mod.measured_staleness(new_last, r)
         return new_state, metrics
 
     return epoch_fn
+
+
+OPT_SCOPE = "digest/opt"
+
+
+@jax.named_scope(OPT_SCOPE)
+def _opt_step(opt: Optimizer, state: dict, grads) -> tuple:
+    """Global AGG (Algorithm 1 line 13), the uniform average of the
+    per-subgraph gradients, then the optimizer's update: (params,
+    opt_state, step)."""
+    mean_grads = jax.tree.map(lambda g: jnp.mean(g, axis=0), grads)
+    params, opt_state = opt.update(mean_grads, state["opt_state"],
+                                   state["params"], state["step"])
+    return params, opt_state, state["step"] + 1
+
+
+METRICS_SCOPE = "digest/metrics"
+
+
+@jax.named_scope(METRICS_SCOPE)
+def _epoch_metrics(losses, logits, labels, mask, eps, last_push, r) -> dict:
+    """The epoch's reported metrics: mean loss, training micro-F1, the
+    staleness probe's eps and, with fault state, the push age."""
+    metrics = {"loss": jnp.mean(losses),
+               "train_f1": micro_f1(logits, labels,
+                                    mask.astype(jnp.float32)),
+               "staleness_eps": eps}
+    if last_push is not None:
+        metrics["push_age"] = faults_mod.measured_staleness(last_push, r)
+    return metrics
 
 
 # ---------------------------------------------------------------------------
@@ -970,11 +1025,13 @@ def make_sampled_epoch_fn(cfg: GNNConfig, opt: Optimizer,
                       jnp.zeros((0,) + x_loc.shape), logits)
 
     def step_fn(state: dict, data: dict, batch: dict) -> tuple[dict, dict]:
-        r = state["epoch"] + 1
         x_global = data["x_global"]
-        x_halo0 = x_global[data["halo_ids_x"]]
+        with jax.named_scope(INPUTS_SCOPE):
+            r = state["epoch"] + 1
+            x_halo0 = x_global[data["halo_ids_x"]]
         cache, pcache = _digest_pull(cfg, settings, state, data, mesh, r)
-        x_local = x_global[data["local_ids"]]
+        with jax.named_scope(INPUTS_SCOPE):
+            x_local = x_global[data["local_ids"]]
         if settings.sample_estimator == "cv":
             hist = state["hist"]
         else:
@@ -985,21 +1042,19 @@ def make_sampled_epoch_fn(cfg: GNNConfig, opt: Optimizer,
             in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0),
             what="per-subgraph loss",
             check_vma=vma_checkable(cfg.backend))
-        (losses, (push_reps, logits)), grads = vg(
-            state["params"], x_local, x_halo0, cache, pcache, hist,
-            data["struct"], data["labels"], batch["seed_mask"],
-            batch["edge_scale"], batch["edge_keep"])
-
-        mean_grads = jax.tree.map(lambda g: jnp.mean(g, axis=0), grads)
-        params, opt_state = opt.update(mean_grads, state["opt_state"],
-                                       state["params"], state["step"])
+        with jax.named_scope(LOSS_SCOPE):
+            (losses, (push_reps, logits)), grads = vg(
+                state["params"], x_local, x_halo0, cache, pcache, hist,
+                data["struct"], data["labels"], batch["seed_mask"],
+                batch["edge_scale"], batch["edge_keep"])
+        params, opt_state, step = _opt_step(opt, state, grads)
 
         (new_store, new_residual, eps, new_last, new_pstore,
          new_hist) = _digest_push(cfg, settings, state, data, push_reps,
                                   mesh, r)
 
-        train_acc = micro_f1(logits, data["labels"],
-                             batch["seed_mask"].astype(jnp.float32))
+        metrics = _epoch_metrics(losses, logits, data["labels"],
+                                 batch["seed_mask"], eps, new_last, r)
         # The CV history refreshes every step: the padded SPMD step
         # computes every local row's representation anyway, so the CV
         # baseline for in-subgraph rows is at most one step stale (the
@@ -1007,7 +1062,7 @@ def make_sampled_epoch_fn(cfg: GNNConfig, opt: Optimizer,
         new_state = {"params": params, "opt_state": opt_state,
                      "store": new_store, "cache": cache,
                      "hist": push_reps if n_hidden > 0 else state["hist"],
-                     "epoch": r, "step": state["step"] + 1}
+                     "epoch": r, "step": step}
         if new_residual is not None:
             new_state["push_residual"] = new_residual
         if new_pstore is not None:
@@ -1015,12 +1070,9 @@ def make_sampled_epoch_fn(cfg: GNNConfig, opt: Optimizer,
             new_state["predictor"] = new_hist
         if pcache is not None:
             new_state["pcache"] = pcache
-        metrics = {"loss": jnp.mean(losses), "train_f1": train_acc,
-                   "staleness_eps": eps}
         if new_last is not None:
             new_state["push_ok"] = state["push_ok"]
             new_state["last_push_round"] = new_last
-            metrics["push_age"] = faults_mod.measured_staleness(new_last, r)
         return new_state, metrics
 
     return step_fn

@@ -42,6 +42,14 @@ from repro.nn import ParamSpec, dense
 
 Pytree = Any
 
+# Named scopes of a layer's parts, nested in its ``layer_{ell}`` scope:
+# they name every device op in the compiled program's op metadata, so a
+# profile separates the split aggregation from the dense transforms and
+# GAT's attention scores.
+AGGREGATE_SCOPE = "aggregate"
+TRANSFORM_SCOPE = "transform"
+ATTENTION_SCOPE = "attention"
+
 
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
@@ -211,24 +219,29 @@ def _halo_agg(cfg, ref: dict, wts: jax.Array) -> jax.Array:
 
 
 def _gcn_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
-    ref = _as_halo_ref(x_halo, struct)
-    agg = spmm(struct["in_nbr"], struct["in_wts"], _pad_sentinel(x_local),
-               backend=cfg.backend)
-    agg = agg + _halo_agg(cfg, ref, ref["wts"])
-    return dense(agg, p["w"], p["b"])
+    with jax.named_scope(AGGREGATE_SCOPE):
+        ref = _as_halo_ref(x_halo, struct)
+        agg = spmm(struct["in_nbr"], struct["in_wts"],
+                   _pad_sentinel(x_local), backend=cfg.backend)
+        agg = agg + _halo_agg(cfg, ref, ref["wts"])
+    with jax.named_scope(TRANSFORM_SCOPE):
+        return dense(agg, p["w"], p["b"])
 
 
 def _sage_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
     # Mean aggregator: row-normalize the (GCN) weights to a mean.
-    ref = _as_halo_ref(x_halo, struct)
-    in_w, out_w = struct["in_wts"], ref["wts"]
-    denom = jnp.sum(in_w, axis=1, keepdims=True) + jnp.sum(
-        out_w, axis=1, keepdims=True)
-    denom = jnp.maximum(denom, 1e-12)
-    agg = spmm(struct["in_nbr"], in_w / denom, _pad_sentinel(x_local),
-               backend=cfg.backend)
-    agg = agg + _halo_agg(cfg, ref, out_w / denom)
-    return (dense(x_local, p["w_self"]) + dense(agg, p["w_nbr"]) + p["b"])
+    with jax.named_scope(AGGREGATE_SCOPE):
+        ref = _as_halo_ref(x_halo, struct)
+        in_w, out_w = struct["in_wts"], ref["wts"]
+        denom = jnp.sum(in_w, axis=1, keepdims=True) + jnp.sum(
+            out_w, axis=1, keepdims=True)
+        denom = jnp.maximum(denom, 1e-12)
+        agg = spmm(struct["in_nbr"], in_w / denom, _pad_sentinel(x_local),
+                   backend=cfg.backend)
+        agg = agg + _halo_agg(cfg, ref, out_w / denom)
+    with jax.named_scope(TRANSFORM_SCOPE):
+        return (dense(x_local, p["w_self"]) + dense(agg, p["w_nbr"])
+                + p["b"])
 
 
 def _multihead_spmm(nbr, att, z_pad, backend):
@@ -248,63 +261,71 @@ def _gat_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
     S = x_local.shape[0]
     ref = _as_halo_ref(x_halo, struct)
     heads, dh = p["a_src"].shape
-    z_loc = jnp.einsum("sd,dhk->shk", x_local, p["w"])    # (S, heads, dh)
-    if "zdata" in ref:
-        # Pre-projected halo table (projected_halo_ref): rows are already
-        # W·h̃, projected ONCE per owner shard at pull time instead of
-        # once per subgraph per epoch — the owner-shard dedup path.  Only
-        # the (cheap) attention scores below still use this epoch's
-        # a_src.
-        z_out = ref["zdata"].astype(jnp.float32)
-        if "zscale" in ref:
-            z_out = z_out * ref["zscale"]
-        T = z_out.shape[0]                        # slab rows incl. sentinel
-        z_out = z_out.reshape(T, heads, dh)
-    else:
-        # Legacy: dequantize the raw halo rows and project here.  When the
-        # slab enters vmap unbatched (a shared store slab) this happens
-        # once for all subgraphs; with device-local per-subgraph slabs it
-        # is the M×-redundant projection the dedup path removes.
-        x_out = ref["data"].astype(jnp.float32)
-        if "scale" in ref:
-            x_out = x_out * ref["scale"]
-        if "pdata" in ref:
-            # SAT prediction before projection — exact by linearity of W.
-            p_out = ref["pdata"].astype(jnp.float32)
-            if "pscale" in ref:
-                p_out = p_out * ref["pscale"]
-            x_out = x_out + jnp.float32(ref["gamma"].value) * p_out
-        T = x_out.shape[0]                        # slab rows incl. sentinel
-        z_out = jnp.einsum("sd,dhk->shk", x_out, p["w"])  # (T, heads, dh)
+    with jax.named_scope(TRANSFORM_SCOPE):
+        z_loc = jnp.einsum("sd,dhk->shk", x_local, p["w"])  # (S, heads, dh)
+        if "zdata" in ref:
+            # Pre-projected halo table (projected_halo_ref): rows are
+            # already W·h̃, projected ONCE per owner shard at pull time
+            # instead of once per subgraph per epoch — the owner-shard
+            # dedup path.  Only the (cheap) attention scores below still
+            # use this epoch's a_src.
+            z_out = ref["zdata"].astype(jnp.float32)
+            if "zscale" in ref:
+                z_out = z_out * ref["zscale"]
+            T = z_out.shape[0]                    # slab rows incl. sentinel
+            z_out = z_out.reshape(T, heads, dh)
+        else:
+            # Legacy: dequantize the raw halo rows and project here.  When
+            # the slab enters vmap unbatched (a shared store slab) this
+            # happens once for all subgraphs; with device-local
+            # per-subgraph slabs it is the M×-redundant projection the
+            # dedup path removes.
+            x_out = ref["data"].astype(jnp.float32)
+            if "scale" in ref:
+                x_out = x_out * ref["scale"]
+            if "pdata" in ref:
+                # SAT prediction before projection — exact by linearity
+                # of W.
+                p_out = ref["pdata"].astype(jnp.float32)
+                if "pscale" in ref:
+                    p_out = p_out * ref["pscale"]
+                x_out = x_out + jnp.float32(ref["gamma"].value) * p_out
+            T = x_out.shape[0]                    # slab rows incl. sentinel
+            z_out = jnp.einsum("sd,dhk->shk", x_out,
+                               p["w"])            # (T, heads, dh)
 
-    s_dst = jnp.einsum("shk,hk->sh", z_loc, p["a_dst"])   # (S, heads)
-    src_loc = jnp.einsum("shk,hk->sh", z_loc, p["a_src"])  # (S, heads)
-    src_out = jnp.einsum("shk,hk->sh", z_out, p["a_src"])  # (T, heads)
+    with jax.named_scope(ATTENTION_SCOPE):
+        s_dst = jnp.einsum("shk,hk->sh", z_loc, p["a_dst"])   # (S, heads)
+        src_loc = jnp.einsum("shk,hk->sh", z_loc, p["a_src"])  # (S, heads)
+        src_out = jnp.einsum("shk,hk->sh", z_out, p["a_src"])  # (T, heads)
 
-    def _scores(nbr, src_table, n_cols):
-        s_src = jnp.take(src_table, nbr, axis=0)           # (S, D, heads)
-        e = jax.nn.leaky_relu(s_dst[:, None, :] + s_src, 0.2)
-        valid = (nbr < n_cols)[..., None]
-        return jnp.where(valid, e, -1e30), valid
+        def _scores(nbr, src_table, n_cols):
+            s_src = jnp.take(src_table, nbr, axis=0)       # (S, D, heads)
+            e = jax.nn.leaky_relu(s_dst[:, None, :] + s_src, 0.2)
+            valid = (nbr < n_cols)[..., None]
+            return jnp.where(valid, e, -1e30), valid
 
-    src_loc_pad = jnp.concatenate(
-        [src_loc, jnp.zeros((1, heads), src_loc.dtype)], 0)
-    e_in, v_in = _scores(struct["in_nbr"], src_loc_pad, S)
-    e_out, v_out = _scores(ref["nbr"], src_out, T - 1)
+        src_loc_pad = jnp.concatenate(
+            [src_loc, jnp.zeros((1, heads), src_loc.dtype)], 0)
+        e_in, v_in = _scores(struct["in_nbr"], src_loc_pad, S)
+        e_out, v_out = _scores(ref["nbr"], src_out, T - 1)
 
-    m = jnp.maximum(jnp.max(e_in, axis=1), jnp.max(e_out, axis=1))
-    m = jax.lax.stop_gradient(m)                           # (S, heads)
-    p_in = jnp.exp(e_in - m[:, None, :]) * v_in
-    p_out = jnp.exp(e_out - m[:, None, :]) * v_out
-    denom = (jnp.sum(p_in, axis=1) + jnp.sum(p_out, axis=1) + 1e-16)
-    a_in = p_in / denom[:, None, :]                        # (S, Din, heads)
-    a_out = p_out / denom[:, None, :]
+        m = jnp.maximum(jnp.max(e_in, axis=1), jnp.max(e_out, axis=1))
+        m = jax.lax.stop_gradient(m)                       # (S, heads)
+        p_in = jnp.exp(e_in - m[:, None, :]) * v_in
+        p_out = jnp.exp(e_out - m[:, None, :]) * v_out
+        denom = (jnp.sum(p_in, axis=1) + jnp.sum(p_out, axis=1) + 1e-16)
+        a_in = p_in / denom[:, None, :]                    # (S, Din, heads)
+        a_out = p_out / denom[:, None, :]
 
-    z_loc_pad = jnp.concatenate(
-        [z_loc, jnp.zeros((1,) + z_loc.shape[1:], z_loc.dtype)], 0)
-    out = _multihead_spmm(struct["in_nbr"], a_in, z_loc_pad, cfg.backend)
-    out = out + _multihead_spmm(ref["nbr"], a_out, z_out, cfg.backend)
-    return out + p["b"]
+    with jax.named_scope(AGGREGATE_SCOPE):
+        z_loc_pad = jnp.concatenate(
+            [z_loc, jnp.zeros((1,) + z_loc.shape[1:], z_loc.dtype)], 0)
+        out = _multihead_spmm(struct["in_nbr"], a_in, z_loc_pad,
+                              cfg.backend)
+        out = out + _multihead_spmm(ref["nbr"], a_out, z_out, cfg.backend)
+    with jax.named_scope(TRANSFORM_SCOPE):
+        return out + p["b"]
 
 
 _LAYERS = {"gcn": _gcn_layer, "sage": _sage_layer, "gat": _gat_layer}
@@ -335,31 +356,36 @@ def _cv_weights(in_wts: jax.Array, samp: dict) -> tuple:
 
 
 def _gcn_layer_cv(cfg, p, x_local, h_hist, x_halo, struct, samp):
-    ref = _as_halo_ref(x_halo, struct)
-    w_fresh, w_resid = _cv_weights(struct["in_wts"], samp)
-    agg = spmm(struct["in_nbr"], w_fresh, _pad_sentinel(x_local),
-               backend=cfg.backend)
-    agg = agg + spmm(struct["in_nbr"], w_resid, _pad_sentinel(h_hist),
-                     backend=cfg.backend)
-    agg = agg + _halo_agg(cfg, ref, ref["wts"])
-    return dense(agg, p["w"], p["b"])
+    with jax.named_scope(AGGREGATE_SCOPE):
+        ref = _as_halo_ref(x_halo, struct)
+        w_fresh, w_resid = _cv_weights(struct["in_wts"], samp)
+        agg = spmm(struct["in_nbr"], w_fresh, _pad_sentinel(x_local),
+                   backend=cfg.backend)
+        agg = agg + spmm(struct["in_nbr"], w_resid, _pad_sentinel(h_hist),
+                         backend=cfg.backend)
+        agg = agg + _halo_agg(cfg, ref, ref["wts"])
+    with jax.named_scope(TRANSFORM_SCOPE):
+        return dense(agg, p["w"], p["b"])
 
 
 def _sage_layer_cv(cfg, p, x_local, h_hist, x_halo, struct, samp):
     # Same full-neighborhood mean denominator as _sage_layer: the CV
     # split redistributes the numerator, not the normalization.
-    ref = _as_halo_ref(x_halo, struct)
-    in_w, out_w = struct["in_wts"], ref["wts"]
-    denom = jnp.sum(in_w, axis=1, keepdims=True) + jnp.sum(
-        out_w, axis=1, keepdims=True)
-    denom = jnp.maximum(denom, 1e-12)
-    w_fresh, w_resid = _cv_weights(in_w, samp)
-    agg = spmm(struct["in_nbr"], w_fresh / denom, _pad_sentinel(x_local),
-               backend=cfg.backend)
-    agg = agg + spmm(struct["in_nbr"], w_resid / denom,
-                     _pad_sentinel(h_hist), backend=cfg.backend)
-    agg = agg + _halo_agg(cfg, ref, out_w / denom)
-    return (dense(x_local, p["w_self"]) + dense(agg, p["w_nbr"]) + p["b"])
+    with jax.named_scope(AGGREGATE_SCOPE):
+        ref = _as_halo_ref(x_halo, struct)
+        in_w, out_w = struct["in_wts"], ref["wts"]
+        denom = jnp.sum(in_w, axis=1, keepdims=True) + jnp.sum(
+            out_w, axis=1, keepdims=True)
+        denom = jnp.maximum(denom, 1e-12)
+        w_fresh, w_resid = _cv_weights(in_w, samp)
+        agg = spmm(struct["in_nbr"], w_fresh / denom,
+                   _pad_sentinel(x_local), backend=cfg.backend)
+        agg = agg + spmm(struct["in_nbr"], w_resid / denom,
+                         _pad_sentinel(h_hist), backend=cfg.backend)
+        agg = agg + _halo_agg(cfg, ref, out_w / denom)
+    with jax.named_scope(TRANSFORM_SCOPE):
+        return (dense(x_local, p["w_self"]) + dense(agg, p["w_nbr"])
+                + p["b"])
 
 
 def sampled_struct(struct: dict, samp: dict, sentinel: int) -> dict:
