@@ -18,7 +18,7 @@ from repro.core import halo_exchange as hx
 from repro.graph.generators import community_powerlaw_graph
 from repro.graph.partition import build_chunk_worklist, build_partitions
 from repro.kernels.flash_attention import multi_head_attention
-from repro.kernels.spmm import (SKIP_OCCUPANCY_MAX, halo_spmm_pallas,
+from repro.kernels.spmm import (halo_spmm_pallas,
                                 halo_spmm_skip_pallas,
                                 halo_spmm_stream_pallas, spmm)
 from repro.models.attention import chunked_attention
@@ -83,13 +83,11 @@ def _order_sweep() -> list[dict]:
     pinned-occupancy slabs above): the same community power-law graph is
     partitioned with order="none" and order="rcm" and the resulting
     stacked chunk worklists compared — chunks visited, bytes streamed per
-    layer (int8 slab convention of the sweep above) and, decisively,
-    which streaming backend ``halo_spmm``'s static selection picks at
-    the measured occupancy.  The structural claim recorded here: RCM
-    drops occupancy across the SKIP_OCCUPANCY_MAX crossover, so the
-    chunk-skipping kernel is auto-selected where the identity layout
-    still pays the dense stream.  us_per_call is the host-side
-    partition+ordering build time (the cost of the locality pass)."""
+    layer (int8 slab convention of the sweep above).  The structural
+    claim recorded here: RCM lowers occupancy, so the chunk-skipping
+    kernel (``backend="pallas_skip"``) streams fewer bytes than under
+    the identity layout.  us_per_call is the host-side partition+ordering
+    build time (the cost of the locality pass)."""
     chunk, feat, M = 256, 128, 8
     g = community_powerlaw_graph(num_nodes=40000, seed=0,
                                  name="bench-powerlaw")
@@ -101,16 +99,13 @@ def _order_sweep() -> list[dict]:
                               order_chunk_rows=chunk)
         dt = (time.perf_counter() - t0) * 1e6
         wl = sp.chunk_worklist(chunk)
-        backend = ("pallas_skip" if wl.occupancy <= SKIP_OCCUPANCY_MAX
-                   else "pallas_stream")
         rows.append({
             "name": f"kernel/halo_spmm_order_{order}",
             "us_per_call": round(dt, 1),
             "occupancy": round(wl.occupancy, 4),
             "chunks_visited": wl.visited_chunks,
             "chunks_total": wl.total_pairs,
-            "bytes_streamed": wl.visited_chunks * chunk_bytes,
-            "selected_backend": backend})
+            "bytes_streamed": wl.visited_chunks * chunk_bytes})
     return rows
 
 

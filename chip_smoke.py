@@ -8,24 +8,28 @@ built and run by the calls ``repro.launch.train_gnn`` makes —
 ``prepare_graph_data`` → ``init_state`` → the jitted epoch with
 ``subgraph_shardings`` (``train_gnn.jit_epoch``) → ``evaluate``.  It
 trains ``--epochs`` epochs (PUSH at epoch 1, PULL at epoch 10) with the
-halo store in fp32 and then in int8, the aggregation in the compiled
-Pallas kernels (``backend="auto"``), evaluates on the full graph, and
-checks that
+halo store in fp32 and then in int8, the aggregation on the TPU ladder
+(``backend="auto"``), evaluates on the full graph, and checks that
 
   * every epoch's loss and the evaluation loss are finite;
   * every aggregation call of the compiled epoch, and each layer's of
-    the compiled evaluation, is a Pallas kernel (``tpu_custom_call``);
-    the layer, side and ladder rung of each are read from the compiled
-    program's op metadata;
+    the compiled evaluation, runs a rung of the ladder: a Pallas kernel
+    (``tpu_custom_call``) or the row gather (the ``while`` loop of
+    ``jit(halo_spmm_gather)``); the layer, side and rung of each are
+    read from the compiled program's op metadata;
   * on real inputs (subgraph 0's features, and its halo slab pulled at
-    epoch 10) the kernels agree with the jnp reference to ``KERNEL_TOL``
-    of the reference's largest magnitude, on every rung: the rung the
-    ladder selects, the resident kernel on a slice of the table, the
-    chunk-skipping kernel with the real worklist, and the SAT predictor
-    epilogue.
+    epoch 10) every rung agrees with the jnp reference to ``KERNEL_TOL``
+    of the reference's largest magnitude: the rung the ladder selects,
+    the resident kernel on a slice of the table, the one-hot stream and
+    the chunk-skipping kernel (by name, with the real worklist), and the
+    SAT predictor epilogue.
 
 ``--backend jnp`` runs the same phases with the jnp reference
 aggregation, for comparison of the times; it skips the kernel checks.
+``--sweep`` only times one aggregation call on each rung, against tables
+of 2k to 16k rows (8 parts of the products-sim ×10 in-ELL's shape:
+15,752 rows, width 48, a quarter of the slots filled), and checks each
+against the jnp reference.
 
 Four chips (``--chips 4``): only the paths that cross chips, each against
 the same path on a one-device mesh in the same process — the collective
@@ -48,6 +52,7 @@ benchmark.
 
   python3 chip_smoke.py                  # one chip
   python3 chip_smoke.py --backend jnp    # one chip, jnp aggregation
+  python3 chip_smoke.py --sweep          # one chip, rung times by table
   python3 chip_smoke.py --chips 4        # the four-chip phase
 """
 from __future__ import annotations
@@ -81,11 +86,16 @@ STORE_ATOL = 1e-5     # four-chip vs one-device store and slab rows
 DENSE_TOL = 2.0 ** -8
 RESIDENT_ROWS = 2048  # table slice small enough for the resident kernel
 
-# The jitted kernel entry point each ladder rung compiles under; the name
-# survives into the compiled custom call's op_name metadata.
+SWEEP_TABLE_ROWS = (2048, 4096, 8192, 16384)
+
+# The jitted entry point each ladder rung compiles under; the name
+# survives into the op_name metadata of the op that marks one call: a
+# Pallas kernel's custom call, or the row gather's ``while`` loop.
 RUNG_OF_KERNEL = {"jit(halo_spmm_pallas)": "resident",
                   "jit(halo_spmm_stream_pallas)": "stream",
-                  "jit(halo_spmm_skip_pallas)": "skip"}
+                  "jit(halo_spmm_skip_pallas)": "skip",
+                  "jit(halo_spmm_gather)": "gather"}
+GATHER = "jit(halo_spmm_gather)"
 
 
 def log(msg: str) -> None:
@@ -191,16 +201,18 @@ def train(g, data, precision: str, mesh, epochs: int, *,
 
 
 def kernel_calls(hlo: str) -> collections.Counter:
-    """Count the Pallas kernels of a compiled program by (layer, side,
-    rung), read from each custom call's op metadata: the layer's named
-    scope (-1 outside one), ``jit(spmm)`` for the in-subgraph side, and
-    the jitted kernel entry point for the ladder rung."""
+    """Count the aggregation calls of a compiled program by (layer, side,
+    rung), read from the op metadata of each Pallas custom call and each
+    row-gather ``while`` loop: the layer's named scope (-1 outside one),
+    ``jit(spmm)`` for the in-subgraph side, and the jitted entry point
+    for the ladder rung."""
     calls = collections.Counter()
     for line in hlo.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
-            continue
         found = re.search(r'op_name="([^"]*)"', line)
         op = found.group(1) if found else ""
+        if not ('custom_call_target="tpu_custom_call"' in line
+                or (" while(" in line and GATHER in op)):
+            continue
         layer = re.search(r"layer_(\d+)", op)
         calls[(int(layer.group(1)) if layer else -1,
                "local" if "jit(spmm)" in op else "halo",
@@ -212,7 +224,7 @@ def kernel_calls(hlo: str) -> collections.Counter:
 def log_kernel_calls(label: str, calls: collections.Counter) -> None:
     for (layer, side, rung), n in sorted(calls.items()):
         where = f"layer {layer} {side}" if layer >= 0 else side
-        log(f"{label} {where}: {rung} kernel" + (f" x{n}" if n > 1 else ""))
+        log(f"{label} {where}: {rung} rung" + (f" x{n}" if n > 1 else ""))
 
 
 def reference_cases(run: dict) -> dict:
@@ -239,12 +251,12 @@ def reference_cases(run: dict) -> dict:
     return {
         "layer 0 local": (ladder, local, {}),
         f"layer 0 local, first {r} table rows": (ladder, head, {}),
-        "layer 1 halo": (ladder, halo,
-                         dict(occupancy=cfg.halo_occupancy)),
+        "layer 1 halo": (ladder, halo, {}),
+        "layer 1 halo, one-hot stream": ("pallas_stream", halo, {}),
         "layer 1 halo, chunk-skipping": ("pallas_skip", halo, {}),
         "layer 1 halo + SAT epilogue (gamma 0.5)": (
             ladder, dict(halo, pdata=pslab, pscale=pscale),
-            dict(occupancy=cfg.halo_occupancy, gamma=0.5)),
+            dict(gamma=0.5)),
     }
 
 
@@ -268,6 +280,56 @@ def kernel_vs_reference(run: dict) -> dict:
         out[name] = (rungs, float(np.abs(got - want).max())
                      / max(float(np.abs(want).max()), 1e-30))
     return out
+
+
+def rung_sweep_phase(args) -> list:
+    """Time one aggregation call on each rung against tables of
+    ``SWEEP_TABLE_ROWS`` rows: 8 parts (vmapped, as the epoch runs them)
+    of 15,752 rows, ELL width 48, a quarter of the slots filled with
+    uniform random ids, 128-wide fp32 rows.  The row gather and the
+    one-hot stream run at every size, the resident kernel where the
+    table fits its budget."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels.spmm import (halo_spmm, halo_spmm_gather,
+                                    select_kernel)
+
+    parts, rows, deg, fill, feat = 8, 15752, 48, 0.2512, 128
+    rng = np.random.default_rng(0)
+    failures = []
+    for n_tab in SWEEP_TABLE_ROWS:
+        real = rng.random((parts, rows, deg)) < fill
+        nbr = np.where(real, rng.integers(0, n_tab - 1, real.shape),
+                       n_tab - 1).astype(np.int32)
+        wts = (rng.random(real.shape) * real).astype(np.float32)
+        table = rng.normal(size=(parts, n_tab, feat)).astype(np.float32)
+        table[:, -1] = 0
+        args_ = tuple(jnp.asarray(x) for x in (nbr, wts, table))
+        want = np.asarray(jax.vmap(functools.partial(
+            halo_spmm, backend="jnp"))(*args_))
+        rungs = {"gather": halo_spmm_gather,
+                 "stream": functools.partial(halo_spmm,
+                                             backend="pallas_stream")}
+        if select_kernel((n_tab, feat), jnp.float32) == "resident":
+            rungs["resident"] = functools.partial(halo_spmm,
+                                                  backend="pallas")
+        for rung, fn in rungs.items():
+            compiled = jax.jit(jax.vmap(fn)).lower(*args_).compile()
+            got = np.asarray(compiled(*args_))
+            times = []
+            for _ in range(5):
+                t = time.perf_counter()
+                jax.block_until_ready(compiled(*args_))
+                times.append(time.perf_counter() - t)
+            err = float(np.abs(got - want).max()) / float(
+                np.abs(want).max())
+            check(failures, err <= KERNEL_TOL,
+                  f"[sweep] table {n_tab} rows, {rung} rung: median "
+                  f"{1000 * float(np.median(times)):.3f} ms of 5; max "
+                  f"error {err:.3e} of max |ref|")
+    return failures
 
 
 def one_chip_phase(args) -> list:
@@ -315,18 +377,18 @@ def one_chip_phase(args) -> list:
                 check(failures, sides >= {(layer, side) for layer in layers
                                           for side in ("local", "halo")},
                       f"[{precision}] every aggregation call of the "
-                      f"compiled epoch is a Pallas kernel")
+                      f"compiled epoch runs a ladder rung")
                 calls = kernel_calls(run["eval_hlo"])
                 log_kernel_calls(f"[{precision}] evaluate", calls)
                 sides = {(layer, side) for layer, side, _ in calls}
                 check(failures, sides >= {(layer, "local")
                                           for layer in layers},
                       f"[{precision}] every layer of the compiled "
-                      f"evaluation aggregates in a Pallas kernel")
+                      f"evaluation aggregates on a ladder rung")
                 for name, (rungs, err) in kernel_vs_reference(run).items():
                     check(failures, err <= KERNEL_TOL and rungs != [],
                           f"[{precision}] {name} ({'/'.join(rungs)} "
-                          f"kernel): max error {err:.3e} of max |ref| "
+                          f"rung): max error {err:.3e} of max |ref| "
                           f"(tolerance {KERNEL_TOL})")
         except Exception:  # noqa: BLE001 — report, then run the next phase
             traceback.print_exc()
@@ -449,7 +511,7 @@ def four_chip_phase(args) -> list:
             f"compile {compile_s:.2f}s, run {run_s:.3f}s")
         log_kernel_calls(f"[{name}] serve_query_sharded", calls)
         check(failures, sum(calls.values()) >= 2,
-              f"[{name}] the sharded query aggregates in Pallas kernels")
+              f"[{name}] the sharded query aggregates on ladder rungs")
         served[name] = np.concatenate([out[m, :len(ids)]
                                        for m, ids in enumerate(gids)])
     exact = np.asarray(exact)[np.concatenate(gids)]
@@ -477,12 +539,16 @@ def main() -> int:
     ap.add_argument("--epochs", type=int, default=10,
                     help="epochs per run (the 10th pulls the store)")
     ap.add_argument("--backend", default="auto", choices=("auto", "jnp"),
-                    help="one-chip aggregation: 'auto' (the compiled "
-                         "Pallas kernels) or 'jnp' (the reference, for "
+                    help="one-chip aggregation: 'auto' (the TPU "
+                         "ladder) or 'jnp' (the reference, for "
                          "comparison; skips the kernel checks)")
+    ap.add_argument("--sweep", action="store_true",
+                    help="one chip: only time one aggregation call on "
+                         "each rung against tables of "
+                         f"{SWEEP_TABLE_ROWS} rows")
     args = ap.parse_args()
-    if args.chips != 1 and args.backend != "auto":
-        ap.error("--backend applies to the one-chip phase only")
+    if args.chips != 1 and (args.backend != "auto" or args.sweep):
+        ap.error("--backend and --sweep apply to the one-chip phase only")
     devices = require_tpu(args.chips)
 
     from repro.launch.compile_cache import enable_compile_cache
@@ -492,7 +558,8 @@ def main() -> int:
     log(f"device: {devices[0].device_kind} x{len(devices)}; compile cache "
         f"{cache} ({entries} entries)")
     t = time.perf_counter()
-    phase = one_chip_phase if args.chips == 1 else four_chip_phase
+    phase = (four_chip_phase if args.chips != 1 else
+             rung_sweep_phase if args.sweep else one_chip_phase)
     failures = phase(args)
     log(f"total {time.perf_counter() - t:.1f}s")
     if failures:

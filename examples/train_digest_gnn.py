@@ -81,7 +81,6 @@ def main():
                     num_layers=3 if args.model != "gat" else 2,
                     in_dim=g.features.shape[1], hidden_dim=args.hidden,
                     num_classes=int(g.labels.max()) + 1, heads=4,
-                    halo_occupancy=data["_worklist"].occupancy,
                     gat_halo_dedup=not args.no_gat_dedup)
     pc = param_count(gnn_specs(cfg))
     print(f"dataset={g.name} nodes={g.num_nodes} edges={g.num_edges} "

@@ -124,7 +124,7 @@ def prepare_graph_data(g: Graph, num_parts: int, method: str = "greedy",
         # device-local pulled slabs directly; the store-slot / global-id
         # remaps live on StackedPartitions for whole-slab consumers.
         # The chunk worklist rides along with the adjacency it was
-        # computed from: the streamed halo_spmm skips every
+        # computed from: the chunk-skipping kernel skips every
         # (row_block, chunk) pair it proves empty (geometry: the kernels'
         # 128-row blocks over the BLOCK_ROWS-padded S rows, chunk_rows-
         # row chunks over the (H+1)-row slab).
@@ -176,8 +176,7 @@ def prepare_graph_data(g: Graph, num_parts: int, method: str = "greedy",
         "full_val_mask": jnp.asarray(full.val_mask),
         "full_test_mask": jnp.asarray(full.test_mask),
         # Host-side metadata (not traced).  _worklist carries the static
-        # occupancy the launchers copy into GNNConfig.halo_occupancy for
-        # the skip-vs-dense stream selection.
+        # occupancy of the chunk-skipping kernel's worklist.
         "_sp": sp,
         "_graph": g,
         "_worklist": worklist,

@@ -95,8 +95,9 @@ history holds.
 Occupancy worklist (the chunk-skipping streamed read path)
 ----------------------------------------------------------
 
-Non-pull epochs read the pulled per-subgraph slabs through the streamed
-``halo_spmm`` kernels, whose DMA schedule can consult a **static
+Non-pull epochs read the pulled per-subgraph slabs through ``halo_spmm``;
+its chunk-skipping stream kernel (``backend="pallas_skip"``) schedules
+its DMA from a **static
 (row-block × chunk) worklist** computed once at partition time
 (:func:`repro.graph.partition.build_chunk_worklist` /
 ``StackedPartitions.chunk_worklist``).  Format — CSR padded to a static
@@ -118,9 +119,9 @@ Geometry is bound to the kernel tiling: 128-row output blocks
 chunks over the (H+1)-row slab — rebuild the worklist when either
 changes.  The owner-sharded slot layout is what makes this pay: each
 subgraph's halo references cluster in a few owner shards, so measured
-occupancy (``ChunkWorklist.occupancy``, the static kernel-selection
-signal threaded through ``GNNConfig.halo_occupancy``) sits far below 1
-and streamed bytes scale with occupied work, not slab size.
+occupancy (``ChunkWorklist.occupancy``) sits far below 1 and the
+chunk-skipping kernel's streamed bytes scale with occupied work, not
+slab size.
 
 Slab layout under ``build_partitions(order=...)``: every slab is laid
 out as contiguous owner runs (the slab-side mirror of the owner-sharded

@@ -130,7 +130,6 @@ class ServeConfig:
     backend: str = "auto"
     resident_max_bytes: Optional[int] = None
     chunk_rows: Optional[int] = None
-    skip_occupancy_max: Optional[float] = None
 
     def __post_init__(self):
         if self.storage not in PRECISIONS:
@@ -448,8 +447,7 @@ def _side_spmm(scfg: ServeConfig, side: dict, wts) -> jax.Array:
     return halo_spmm(side["nbr"], wts, side["data"], side.get("scale"),
                      backend=scfg.backend,
                      resident_max_bytes=scfg.resident_max_bytes,
-                     chunk_rows=scfg.chunk_rows,
-                     skip_occupancy_max=scfg.skip_occupancy_max)
+                     chunk_rows=scfg.chunk_rows)
 
 
 def _batch_top_layer(cfg, scfg: ServeConfig, p, h_self, sides):

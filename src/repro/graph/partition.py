@@ -22,9 +22,9 @@ reorders each part's local rows with reverse Cuthill-McKee over the
 induced subgraph (and re-lays each per-subgraph halo slab's owner runs by
 first-referencing row) so consecutive 128-row output blocks reference
 clustered halo-slab ranges.  That drives the static
-:class:`ChunkWorklist` occupancy down into the regime where the
-chunk-skipping streamed kernel (``halo_spmm_skip_pallas``) is selected
-and streams a fraction of the dense bytes.  The ordering is a pure
+:class:`ChunkWorklist` occupancy down, so the chunk-skipping streamed
+kernel (``halo_spmm_skip_pallas``) streams a fraction of the dense
+bytes.  The ordering is a pure
 permutation of local rows (per-row ELL edge order, the owner-sharded
 store layout and the PullPlan routing are untouched), guarded per part:
 a part keeps its identity order if RCM would not reduce its visited
@@ -347,8 +347,7 @@ def partition_report(g: Graph, sp: "StackedPartitions",
     The worklist columns score the *locality* of the layout, not just its
     size: ``wl_occupancy`` is the stacked :class:`ChunkWorklist` fraction
     of (row_block × chunk) pairs the streamed halo kernels must visit at
-    ``chunk_rows`` geometry (below ``SKIP_OCCUPANCY_MAX`` the skip kernel
-    is auto-selected), and ``stream_bytes_skip`` / ``stream_bytes_dense``
+    ``chunk_rows`` geometry, and ``stream_bytes_skip`` / ``stream_bytes_dense``
     estimate the per-layer slab traffic of the skip vs dense stream
     (visited resp. all chunks × ``chunk_rows`` slab rows × ``row_bytes``
     per row — default 256 B = the 64-wide fp32 hidden slab).

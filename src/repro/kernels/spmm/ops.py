@@ -2,35 +2,32 @@
 
 Backends (``backend=``; anything else raises):
 
-  * ``"auto"`` (default) — the compiled Pallas kernels when
+  * ``"auto"`` (default) — the ladder below when
     ``jax.default_backend() == "tpu"``, the jnp reference elsewhere (the
     CPU test suite; interpret-mode Pallas is Python-slow and the oracle is
     the same math).
   * ``"jnp"`` — the reference (:mod:`repro.kernels.spmm.ref`).
-  * ``"pallas"`` / ``"pallas_stream"`` / ``"pallas_skip"`` — the compiled
-    kernels: the selection ladder below, or a forced streamed variant.
+  * ``"pallas"`` / ``"pallas_stream"`` / ``"pallas_skip"`` — the ladder
+    below, or a forced one-hot stream kernel.
   * the same three names with an ``_interpret`` suffix — the kernel bodies
     run by the Pallas interpreter (CPU tests of the kernels themselves).
 
 ``spmm`` (the in-subgraph side) and ``halo_spmm`` (the halo side) share
-one kernel ladder, selected by shapes:
+one ladder, selected by the table's static shape and dtype:
 
   * **resident** — the table's 128-wide feature stripe plus its one-hot
     tile fit the ``resident_max_bytes`` VMEM budget (default
-    ``RESIDENT_STRIPE_MAX_BYTES``): one chunk spans the table.
-  * **dense stream** — above the budget: chunked double-buffered DMA of
-    every ``chunk_rows``-row table chunk past the accumulator tile.
-  * **skip stream** — above the budget *and* a (row_block × chunk)
-    worklist is supplied whose static measured ``occupancy`` is at or
-    below ``skip_occupancy_max`` (default ``SKIP_OCCUPANCY_MAX``): stream
-    only the chunks each row block references
-    (:func:`repro.kernels.spmm.halo_pull.halo_spmm_skip_pallas`).  At
-    high occupancy the worklist degenerates to the dense schedule while
-    paying the scalar-prefetch indirection, so the dense stream wins —
-    hence the threshold, overridable per call (it is a static, jit-cache-
-    keyed argument, like every selection knob here).
+    ``RESIDENT_STRIPE_MAX_BYTES``): the Pallas kernel with one chunk
+    spanning the table.
+  * **gather** — above the budget: :func:`halo_spmm_gather`, the
+    compiler's row gather accumulated over the ELL's degree slots.  Its
+    work grows with the ELL's slots, where the one-hot kernels' grows
+    with rows × table rows.
 
-The kernels compute the forward product; the backward pass is the VJP of
+The one-hot stream kernels (``"pallas_stream"``, ``"pallas_skip"``) are
+reached by name only.
+
+The rungs compute the forward product; the backward pass is the VJP of
 the jnp reference (a transposed ELL product XLA lowers as gather +
 scatter-add), attached through ``jax.custom_vjp``, so every backend
 differentiates identically.
@@ -53,14 +50,8 @@ from repro.kernels.spmm.ref import halo_spmm_ref
 # VMEM budget of the resident kernel: the table's feature stripe, its
 # scale row and the (128 × rows) fp32 one-hot tile with its transpose.
 # A 128-wide fp32 table reaches it at ~2.7k rows (int8: ~3.6k rows);
-# above it, the table streams through chunked double-buffered DMA.
+# above it, the table is aggregated by the row gather.
 RESIDENT_STRIPE_MAX_BYTES = 4 * 1024 * 1024
-
-# Highest (row_block × chunk) occupancy at which the chunk-skipping
-# stream is auto-selected over the dense stream.  Above it most chunks
-# are visited anyway and the dense schedule's simpler (non-indirected)
-# prefetch wins; below it DMA bytes shrink proportionally to occupancy.
-SKIP_OCCUPANCY_MAX = 0.5
 
 _KERNELS = ("pallas", "pallas_stream", "pallas_skip")
 BACKENDS = ("auto", "jnp") + _KERNELS + tuple(f"{k}_interpret"
@@ -87,12 +78,10 @@ def vma_checkable(backend: str) -> bool:
 
 def select_kernel(data_shape, data_dtype, *, has_scale: bool = False,
                   pred_dtype=None, has_pscale: bool = False,
-                  has_worklist: bool = False, occupancy: float = None,
-                  resident_max_bytes: int = None,
-                  skip_occupancy_max: float = None) -> str:
-    """The ladder rung (``"resident"``, ``"stream"`` or ``"skip"``) a
-    table of this shape selects — a pure function of static facts, so the
-    launchers can report which kernel each aggregation call runs."""
+                  resident_max_bytes: int = None) -> str:
+    """The ladder rung (``"resident"`` or ``"gather"``) a table of this
+    shape selects — a pure function of static facts, so the launchers can
+    report which rung each aggregation call runs."""
     if resident_max_bytes is None:
         resident_max_bytes = RESIDENT_STRIPE_MAX_BYTES
     rows = resident_rows(data_shape[0])
@@ -103,14 +92,57 @@ def select_kernel(data_shape, data_dtype, *, has_scale: bool = False,
         # The history table rides the same tiles.
         stripe += rows * (bf * jnp.dtype(pred_dtype).itemsize
                           + (4 if has_pscale else 0))
-    if stripe <= resident_max_bytes:
-        return "resident"
-    if skip_occupancy_max is None:
-        skip_occupancy_max = SKIP_OCCUPANCY_MAX
-    if (has_worklist and occupancy is not None
-            and occupancy <= skip_occupancy_max):
-        return "skip"
-    return "stream"
+    return "resident" if stripe <= resident_max_bytes else "gather"
+
+
+@functools.partial(jax.jit, static_argnames=("gamma",))
+def halo_spmm_gather(nbr: jax.Array, wts: jax.Array, data: jax.Array,
+                     scale: jax.Array = None, pdata: jax.Array = None,
+                     pscale: jax.Array = None,
+                     gamma: float = 1.0) -> jax.Array:
+    """Row-gather aggregation, the ladder's rung past the resident budget:
+    :func:`halo_spmm_ref`'s sum in float32, accumulated one degree slot at
+    a time.
+
+    A ``scan`` over the ELL's slots gathers one ``(rows, feat)`` slice of
+    table rows per step, so the ``(rows, deg, feat)`` gathered rows are
+    never live whole (the plain take-then-sum form keeps them all).  The
+    scan reads the ELL transposed, ``(deg, rows)``: a slot's ids are then
+    contiguous, which on a v5e gathers up to 2.7× faster than slicing a
+    column of the ``(rows, deg)`` array (tables of 2k–4k rows; 1.6× at
+    16k rows, even at 104k).  A table stored in another precision (bf16,
+    int8 with per-row scales) is dequantized to float32 once per call,
+    before the loop, at the cost of one float32 copy of the table: on a
+    v5e a loop that gathered int8 rows and their scales slot by slot ran
+    for minutes where either gather alone took milliseconds.  A predictor
+    slab adds ``gamma·dequant(pdata[s])`` per gathered row, as in the
+    reference.
+    """
+    w = wts.astype(jnp.float32)
+
+    def dequant(table, tscale):
+        table = table.astype(jnp.float32)
+        return table if tscale is None else table * tscale
+
+    tables = [(dequant(data, scale), None)]
+    if pdata is not None:
+        tables.append((dequant(pdata, pscale), jnp.float32(gamma)))
+
+    def slot(acc, x):
+        ids, wk = x
+        for table, g in tables:
+            wt = wk if g is None else wk * g
+            acc = acc + wt[:, None] * jnp.take(table, ids, axis=0)
+        return acc, None
+
+    acc = jnp.zeros((nbr.shape[0], data.shape[1]), jnp.float32)
+    # Inside a shard_map the carry varies as the operands do (check_vma).
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (
+        nbr, wts, data, scale, pdata, pscale) if x is not None))
+    if vma:
+        acc = jax.lax.pcast(acc, tuple(sorted(vma)), to="varying")
+    out, _ = jax.lax.scan(slot, acc, (nbr.T, w.T))
+    return out
 
 
 def _pad_dim(x: jax.Array, axis: int, multiple: int,
@@ -127,7 +159,11 @@ def _pad_dim(x: jax.Array, axis: int, multiple: int,
 def _kernel_forward(kind: str, chunk_rows: int, gamma: float,
                     interpret: bool, nbr, wts, data, scale, wl_ids, wl_cnt,
                     pdata, pscale) -> jax.Array:
-    """Pad to the kernels' block sizes, run the ``kind`` kernel, unpad."""
+    """Run the ``kind`` rung; the Pallas kernels padded to their block
+    sizes, and unpadded."""
+    if kind == "gather":
+        return halo_spmm_gather(nbr, wts, data, scale, pdata, pscale,
+                                gamma=gamma)
     rows, feat = nbr.shape[0], data.shape[1]
     nbr_p = _pad_dim(nbr, 0, BLOCK_ROWS, value=data.shape[0] - 1)
     wts_p = _pad_dim(wts, 0, BLOCK_ROWS, value=0)
@@ -199,7 +235,7 @@ def halo_gather(nbr: jax.Array, data: jax.Array,
     weighted sum), and the hot-row cache's miss fill wants raw rows —
     neither can ride :func:`halo_spmm`, whose contraction is fused.
     gcn/sage reductions should keep using :func:`halo_spmm` so they hit
-    the resident/stream/skip selection ladder.
+    the resident/gather selection ladder.
     """
     rows = jnp.take(data, nbr, axis=0).astype(jnp.float32)
     if scale is not None:
@@ -209,16 +245,14 @@ def halo_gather(nbr: jax.Array, data: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("backend", "resident_max_bytes",
-                                    "chunk_rows", "occupancy",
-                                    "skip_occupancy_max", "gamma"))
+                                    "chunk_rows", "gamma"))
 def halo_spmm(nbr: jax.Array, wts: jax.Array, data: jax.Array,
               scale: jax.Array = None, wl_ids: jax.Array = None,
               wl_cnt: jax.Array = None, pdata: jax.Array = None,
               pscale: jax.Array = None, gamma: float = 1.0,
               backend: str = "auto",
-              resident_max_bytes: int = None, chunk_rows: int = None,
-              occupancy: float = None,
-              skip_occupancy_max: float = None) -> jax.Array:
+              resident_max_bytes: int = None,
+              chunk_rows: int = None) -> jax.Array:
     """Fused halo pull+aggregate against the compact HaloExchange slab.
 
     out[i] = Σ_k wts[i,k] · dequant(data[nbr[i,k]]) with optional per-row
@@ -229,24 +263,19 @@ def halo_spmm(nbr: jax.Array, wts: jax.Array, data: jax.Array,
     the data slab's exact layout; see ``repro.core.predictor``) every
     gathered row becomes the staleness-alleviated prediction
     ``dequant(data[s]) + gamma·dequant(pdata[s])`` — fused into the
-    kernel the ladder selects, a second product over the same one-hot
-    tile rather than a second aggregation pass.  ``gamma`` is static
-    (jit-cache-keyed); with ``pdata=None`` the emitted program is exactly
-    the predictor-free one.
+    rung the ladder selects rather than a second aggregation pass.
+    ``gamma`` is static (jit-cache-keyed); with ``pdata=None`` the
+    emitted program is exactly the predictor-free one.
 
-    Optional occupancy-aware streaming (see module docstring for the
-    selection ladder):
+    Ladder arguments (see the module docstring):
 
       wl_ids / wl_cnt: the (row_blocks, max_chunks)/(row_blocks,) chunk
         worklist from ``repro.graph.partition.build_chunk_worklist`` —
-        built with the same ``chunk_rows`` and 128-row blocks.
-      occupancy: the worklist's static measured occupancy
-        (``ChunkWorklist.occupancy``), used for auto-selection; it is a
-        host-side float (jit-cache key), never a traced value.
-      chunk_rows / resident_max_bytes / skip_occupancy_max: overrides of
-        the module-level streaming constants; all static (jit-cache-
-        keyed), so an explicit override never aliases executables traced
-        with the defaults.
+        built with the same ``chunk_rows`` and 128-row blocks; read only
+        by the ``"pallas_skip"`` backends.
+      chunk_rows / resident_max_bytes: overrides of the module-level
+        constants; static (jit-cache-keyed), so an explicit override
+        never aliases executables traced with the defaults.
     """
     backend = resolve_backend(backend)
     if backend == "jnp":
@@ -262,9 +291,8 @@ def halo_spmm(nbr: jax.Array, wts: jax.Array, data: jax.Array,
         kind = select_kernel(
             data.shape, data.dtype, has_scale=scale is not None,
             pred_dtype=pdata.dtype if pdata is not None else None,
-            has_pscale=pscale is not None, has_worklist=has_worklist,
-            occupancy=occupancy, resident_max_bytes=resident_max_bytes,
-            skip_occupancy_max=skip_occupancy_max)
+            has_pscale=pscale is not None,
+            resident_max_bytes=resident_max_bytes)
     else:
         kind = kernel.removeprefix("pallas_")
     if kind != "skip":

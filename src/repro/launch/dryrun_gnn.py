@@ -139,27 +139,10 @@ def main():
                     help="slab rows per streamed halo_spmm chunk (also "
                          "the abstract worklist geometry)")
     ap.add_argument("--resident-max-bytes", type=int, default=None,
-                    help="VMEM budget above which halo_spmm streams "
+                    help="VMEM budget above which halo_spmm leaves "
+                         "the resident kernel for the row gather "
                          "(default: kernel RESIDENT_STRIPE_MAX_BYTES; "
                          "Pallas backends only)")
-    ap.add_argument("--skip-occupancy-max", type=float, default=None,
-                    help="occupancy threshold for the chunk-skipping "
-                         "stream (default: kernel SKIP_OCCUPANCY_MAX; "
-                         "Pallas backends only)")
-    ap.add_argument("--halo-occupancy", type=float, default=None,
-                    help="assumed (row-block x chunk) occupancy of the "
-                         "abstract worklist (no host graph to measure it "
-                         "from); with a Pallas backend, a value at or "
-                         "below the threshold selects the skip-stream "
-                         "kernel in the lowered epoch")
-    ap.add_argument("--order", default=None, choices=("none", "rcm"),
-                    help="modelled local-row layout (no host partitioner "
-                         "in the abstract dry run): sets the default "
-                         "--halo-occupancy to the measured regime of "
-                         "that layout (none=0.85, rcm=0.40 — rcm lands "
-                         "below SKIP_OCCUPANCY_MAX, so a Pallas backend "
-                         "lowers the chunk-skipping stream) and is "
-                         "recorded in the JSON line")
     ap.add_argument("--predictor", default="none",
                     choices=("none", "delta", "ema"),
                     help="SAT staleness predictor kind: history pstore "
@@ -172,12 +155,6 @@ def main():
     ap.add_argument("--predictor-beta", type=float, default=0.5)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    if args.halo_occupancy is None and args.order is not None:
-        # Measured regimes of the two layouts on the community power-law
-        # benchmark graphs (see benchmarks/kernel_bench.py): identity
-        # order sits well above the skip threshold, RCM below it.
-        args.halo_occupancy = {"none": 0.85, "rcm": 0.40}[args.order]
-
     mesh = make_production_mesh(multi_pod=args.multi_pod, pods=args.pods)
     data_axes = [a for a in ("pod", "data") if a in mesh.axis_names]
     num_parts = args.parts_per_device
@@ -188,9 +165,7 @@ def main():
                     hidden_dim=args.hidden, num_classes=64,
                     backend=args.backend,
                     stream_chunk_rows=args.stream_chunk_rows,
-                    resident_max_bytes=args.resident_max_bytes,
-                    skip_occupancy_max=args.skip_occupancy_max,
-                    halo_occupancy=args.halo_occupancy)
+                    resident_max_bytes=args.resident_max_bytes)
     opt = adam(5e-3)
     precision = HaloPrecision(args.precision)
     pcfg = PredictorConfig(kind=args.predictor, gamma=args.predictor_gamma,
@@ -316,8 +291,6 @@ def main():
         "pull_mode": args.pull, "parts_per_device": args.parts_per_device,
         "store_slots": slots, "shard_rows": slots // num_parts,
         "stream_chunk_rows": args.stream_chunk_rows,
-        "halo_occupancy": args.halo_occupancy,
-        "order": args.order,
         "predictor": args.predictor,
         "hlo_flops": float(cost.get("flops", 0.0)),
         "hlo_bytes": float(cost.get("bytes accessed", 0.0)),

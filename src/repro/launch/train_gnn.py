@@ -99,13 +99,11 @@ def subgraph_shardings(data: dict, state: dict, mesh) -> tuple[dict, dict]:
 def model_config(g, data: dict, *, model: str, hidden_dim: int,
                  num_layers: int = 3, **knobs) -> GNNConfig:
     """The GNNConfig for graph ``g`` partitioned into ``data``: its
-    feature and class widths, and the halo worklist's measured occupancy
-    (which selects the chunk-skipping kernel).  ``knobs`` are the other
-    GNNConfig fields (backend, streaming overrides)."""
+    feature and class widths.  ``knobs`` are the other GNNConfig fields
+    (backend, ladder overrides)."""
     return GNNConfig(model=model, num_layers=num_layers,
                      in_dim=g.features.shape[1], hidden_dim=hidden_dim,
-                     num_classes=int(g.labels.max()) + 1,
-                     halo_occupancy=data["_worklist"].occupancy, **knobs)
+                     num_classes=int(g.labels.max()) + 1, **knobs)
 
 
 def jit_epoch(cfg: GNNConfig, opt, settings: TrainSettings, data: dict,
@@ -219,22 +217,16 @@ def main():
                          "reference elsewhere; 'jnp' forces the "
                          "reference, 'pallas*' a kernel, '*_interpret' "
                          "the kernel bodies in the Pallas interpreter — "
-                         "the streaming/skip knobs below act on the "
-                         "Pallas paths (the jnp oracle has no DMA to "
-                         "schedule)")
+                         "the ladder knobs below act on the Pallas "
+                         "paths")
     ap.add_argument("--stream-chunk-rows", type=int, default=None,
-                    help="slab rows per streamed halo_spmm chunk "
-                         "(default: kernel STREAM_CHUNK_ROWS; also sets "
-                         "the precomputed worklist geometry)")
+                    help="slab rows per chunk of the one-hot stream "
+                         "kernels (default: kernel STREAM_CHUNK_ROWS; "
+                         "also sets the precomputed worklist geometry)")
     ap.add_argument("--resident-max-bytes", type=int, default=None,
-                    help="VMEM budget above which halo_spmm streams the "
-                         "slab (default: kernel RESIDENT_STRIPE_MAX_BYTES)")
-    ap.add_argument("--skip-occupancy-max", type=float, default=None,
-                    help="highest measured (row-block x chunk) occupancy "
-                         "at which the chunk-skipping stream is selected "
-                         "over the dense stream (default: kernel "
-                         "SKIP_OCCUPANCY_MAX; >=1 forces it whenever "
-                         "streaming)")
+                    help="VMEM budget above which halo_spmm leaves the "
+                         "resident kernel for the row gather (default: "
+                         "kernel RESIDENT_STRIPE_MAX_BYTES)")
     ap.add_argument("--sampling", action="store_true",
                     help="mini-batch sampled training: fanout-bounded "
                          "neighbor sampling with stale-store control "
@@ -316,7 +308,6 @@ def main():
                        backend=args.backend,
                        stream_chunk_rows=args.stream_chunk_rows,
                        resident_max_bytes=args.resident_max_bytes,
-                       skip_occupancy_max=args.skip_occupancy_max,
                        gat_halo_dedup=not args.no_gat_dedup)
     opt = adam(5e-3)
     from repro.core import PredictorConfig

@@ -129,19 +129,12 @@ class GNNConfig:
     normalize: bool = True        # Algorithm 1 line 11 (L2 per node)
     residual: bool = False
     # Aggregation backend (repro.kernels.spmm.BACKENDS): "auto" runs the
-    # compiled Pallas kernels on a TPU and the jnp reference elsewhere.
+    # kernel ladder on a TPU and the jnp reference elsewhere.
     backend: str = "auto"
-    # -- streamed halo_spmm knobs (static; override the module constants
+    # -- halo_spmm ladder knobs (static; override the module constants
     # of repro.kernels.spmm.ops — None keeps the kernel defaults) -------
     stream_chunk_rows: Optional[int] = None    # STREAM_CHUNK_ROWS
     resident_max_bytes: Optional[int] = None   # RESIDENT_STRIPE_MAX_BYTES
-    skip_occupancy_max: Optional[float] = None  # SKIP_OCCUPANCY_MAX
-    # Measured (row_block × chunk) occupancy of the partition's chunk
-    # worklist (ChunkWorklist.occupancy) — a host-side float the launcher
-    # copies in after building the data; drives skip-vs-dense stream
-    # auto-selection.  None disables the skip stream under the ladder's
-    # own selection (forced "pallas_skip*" backends still work).
-    halo_occupancy: Optional[float] = None
     # GAT: project each owner shard's stale halo rows once per layer at
     # pull time and ship projected rows (True, the dedup path) instead of
     # re-projecting every subgraph's (H+1, d) slab every epoch (False,
@@ -203,9 +196,9 @@ def gnn_specs(cfg: GNNConfig) -> Pytree:
 # ---------------------------------------------------------------------------
 
 def _halo_agg(cfg, ref: dict, wts: jax.Array) -> jax.Array:
-    """Out-of-subgraph fused pull+aggregate with the config's streaming
-    knobs (chunk size, VMEM budget, occupancy-driven chunk skipping)
-    threaded into the kernel selection in repro.kernels.spmm.ops."""
+    """Out-of-subgraph fused pull+aggregate with the config's ladder
+    knobs (chunk size, VMEM budget) threaded into the rung selection in
+    repro.kernels.spmm.ops."""
     g = ref.get("gamma")
     return halo_spmm(ref["nbr"], wts, ref["data"], ref.get("scale"),
                      wl_ids=ref.get("wl_ids"), wl_cnt=ref.get("wl_cnt"),
@@ -213,9 +206,7 @@ def _halo_agg(cfg, ref: dict, wts: jax.Array) -> jax.Array:
                      gamma=g.value if g is not None else 1.0,
                      backend=cfg.backend,
                      resident_max_bytes=cfg.resident_max_bytes,
-                     chunk_rows=cfg.stream_chunk_rows,
-                     occupancy=cfg.halo_occupancy,
-                     skip_occupancy_max=cfg.skip_occupancy_max)
+                     chunk_rows=cfg.stream_chunk_rows)
 
 
 def _gcn_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:

@@ -78,3 +78,36 @@ def test_chip_smoke_kernel_census():
     assert chip_smoke.kernel_calls(hlo) == {
         (0, "local", "stream"): 1, (0, "halo", "skip"): 1,
         (2, "halo", "resident"): 1, (-1, "halo", "stream"): 2}
+
+
+def test_chip_smoke_gather_census():
+    """A row-gather call is its ``while`` loop: one per call, read by the
+    same (layer, side) metadata; the gather's other ops and a loop of
+    another scope are not calls."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+
+    def loop(name, op_name):
+        return (f'%{name} = (s32[], f32[8,15752,128]) while(%t), '
+                f'condition=%c, body=%b, metadata={{op_name="{op_name}"}}')
+    gather = "jit(halo_spmm_gather)"
+    hlo = "\n".join([
+        loop("while.1", "jit(epoch_fn)/digest/loss/vmap(jvp(layer_0))/"
+             f"aggregate/jit(halo_spmm)/{gather}/while"),
+        loop("while.2", "jit(epoch_fn)/digest/loss/vmap(jvp(layer_1))/"
+             f"aggregate/jit(spmm)/jit(halo_spmm)/{gather}/while"),
+        loop("while.3", "jit(evaluate)/layer_2/aggregate/jit(halo_spmm)/"
+             f"{gather}/while"),
+        loop("while.4", "jit(evaluate)/layer_2/aggregate/jit(halo_spmm)/"
+             f"{gather}/while"),
+        loop("while.5", "jit(epoch_fn)/digest/opt/while"),
+        '%fusion.7 = f32[8,15752,128] fusion(%x), metadata={op_name="jit('
+        f'epoch_fn)/vmap(jvp(layer_0))/jit(halo_spmm)/{gather}/while/body/'
+        'closed_call/jit(_take)/gather"}',
+    ])
+    assert chip_smoke.kernel_calls(hlo) == {
+        (0, "halo", "gather"): 1, (1, "local", "gather"): 1,
+        (2, "halo", "gather"): 2}

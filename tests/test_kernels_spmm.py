@@ -7,9 +7,10 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import halo_exchange as hx
-from repro.kernels.spmm import (BACKENDS, halo_spmm, halo_spmm_ref,
+from repro.kernels.spmm import (BACKENDS, RESIDENT_STRIPE_MAX_BYTES,
+                                halo_spmm, halo_spmm_gather, halo_spmm_ref,
                                 halo_spmm_stream_pallas, resolve_backend,
-                                spmm, spmm_ref)
+                                select_kernel, spmm, spmm_ref)
 
 
 def _case(rng, rows, deg, ncols, feat, dtype):
@@ -111,25 +112,91 @@ def test_halo_spmm_stream_single_chunk_exact():
 
 
 def test_halo_spmm_auto_streams_above_threshold():
-    """ops.halo_spmm flips to the streaming kernel once the slab stripe
-    outgrows the VMEM-resident budget.  The threshold is passed as a
-    static argument (part of the jit cache key), so the shrunken value
-    genuinely retraces — a monkeypatched module global would be invisible
-    to an already-cached executable."""
+    """ops.halo_spmm leaves the resident kernel for the row gather once
+    the slab stripe outgrows the VMEM-resident budget.  The threshold is
+    passed as a static argument (part of the jit cache key), so the
+    shrunken value genuinely retraces — a monkeypatched module global
+    would be invisible to an already-cached executable."""
     rng = np.random.default_rng(23)
     nbr, wts, table = _case(rng, 128, 5, 900, 64, np.float32)
     data, scale = hx.quantize_rows(table, hx.HaloPrecision("int8"))
     data = data.at[-1].set(0)
     want = halo_spmm_ref(nbr, wts, data, scale)
-    # stripe = 901 rows · (64 B + 4 B scale) ≈ 61 KiB > 1 KiB → streams
+    # stripe = 901 rows · (64 B + 4 B scale) ≈ 61 KiB > 1 KiB → gathers
     got = halo_spmm(nbr, wts, data, scale, backend="pallas_interpret",
                     resident_max_bytes=1024)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
-    # streamed result == the explicitly-forced streaming backend, bitwise
-    got_forced = halo_spmm(nbr, wts, data, scale,
-                           backend="pallas_stream_interpret")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(got_forced))
+    # the ladder's result == the gather rung called directly, bitwise
+    got_direct = jax.jit(halo_spmm_gather)(nbr, wts, data, scale)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(got_direct))
+
+
+@pytest.mark.parametrize("storage", ["fp32", "bf16", "int8"])
+@pytest.mark.parametrize("pred", [False, True], ids=["plain", "pdata"])
+def test_gather_rung_matches_reference(storage, pred):
+    """Past the resident budget the ladder runs the row gather: equal to
+    the reference and to the one-hot stream kernel within float32
+    tolerance, at every storage precision, with and without the SAT
+    predictor slab; its gradients (the reference VJP) equal the
+    reference's."""
+    rng = np.random.default_rng(29)
+    nbr, wts, table = _case(rng, 200, 7, 900, 64, np.float32)
+    data, scale = hx.quantize_rows(table, hx.HaloPrecision(storage))
+    data = data.at[-1].set(0)
+    kw = {}
+    if pred:
+        pdata, pscale = hx.quantize_rows(table[::-1] * 0.5,
+                                         hx.HaloPrecision(storage))
+        kw = dict(pdata=pdata.at[-1].set(0), pscale=pscale, gamma=0.5)
+    # The 901-row table passes a 1 KiB budget: the ladder gathers.
+    assert select_kernel(data.shape, data.dtype,
+                         has_scale=scale is not None,
+                         pred_dtype=kw["pdata"].dtype if pred else None,
+                         has_pscale=pred and scale is not None,
+                         resident_max_bytes=1024) == "gather"
+
+    def ladder(w, d):
+        return halo_spmm(nbr, w, d, scale, backend="pallas",
+                         resident_max_bytes=1024, **kw)
+
+    def ref(w, d):
+        return halo_spmm_ref(nbr, w, d, scale, **kw)
+
+    got = jax.jit(ladder)(wts, data)
+    stream = halo_spmm(nbr, wts, data, scale,
+                       backend="pallas_stream_interpret", chunk_rows=256,
+                       **kw)
+    np.testing.assert_allclose(got, ref(wts, data), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got, stream, atol=1e-5, rtol=1e-5)
+
+    cot = jnp.asarray(rng.normal(size=got.shape), jnp.float32)
+    argnums = (0, 1) if jnp.issubdtype(data.dtype, jnp.floating) else (0,)
+    grads = jax.grad(lambda w, d: jnp.sum(ladder(w, d) * cot),
+                     argnums=argnums)(wts, data)
+    want = jax.grad(lambda w, d: jnp.sum(ref(w, d) * cot),
+                    argnums=argnums)(wts, data)
+    for g, r in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(r, np.float32),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("n_tab,dtype,rung", [
+    (2048, jnp.float32, "resident"), (2560, jnp.float32, "resident"),
+    (3072, jnp.float32, "gather"), (3584, jnp.int8, "resident"),
+    (15753, jnp.float32, "gather"), (104249, jnp.float32, "gather"),
+    (104249, jnp.int8, "gather")])
+def test_select_kernel_by_table_size(n_tab, dtype, rung):
+    """The ladder keeps the resident kernel while the 128-wide stripe
+    fits ``RESIDENT_STRIPE_MAX_BYTES`` (about 2.7k fp32 rows, 3.6k int8
+    rows) and gathers past it."""
+    assert select_kernel((n_tab, 128), dtype,
+                         has_scale=dtype == jnp.int8) == rung
+    # A budget override moves the threshold: half of it holds none.
+    assert select_kernel((n_tab, 128), dtype,
+                         resident_max_bytes=RESIDENT_STRIPE_MAX_BYTES // 2
+                         ) == "gather"
 
 
 def test_spmm_dense_oracle():

@@ -10,7 +10,7 @@ Covers the PR-4 perf surfaces end to end:
     interpret-mode visit log proving visited chunks == worklist entries,
     strictly fewer than ``row_blocks × n_chunks`` on clustered fixtures
     (synthetic and a real partition);
-  * ops-level selection (occupancy threshold, forced backends);
+  * ops-level selection (the worklist read by the skip backend only);
   * the boundary-aware ``greedy_partition`` halo term (weight-0 identity,
     positive weight reduces Σ|halo| at unchanged balance);
   * the GAT owner-shard projection dedup (pull-epoch forward equality vs
@@ -243,18 +243,17 @@ def test_skip_geometry_guard_and_selection():
         halo_spmm(nbr, wts, table, None, wl_ids=jnp.asarray(fine.ids),
                   wl_cnt=jnp.asarray(fine.cnt),
                   backend="pallas_skip_interpret", chunk_rows=512)
-    # Auto-selection is static and occupancy-gated: with occupancy above
-    # the threshold the (bogus) worklist must NOT be consulted; at or
-    # below it, it is — the geometry guard makes the choice observable.
-    halo_spmm(nbr, wts, table, None, wl_ids=bad_ids,
-              wl_cnt=jnp.asarray(wl.cnt[:1]), backend="pallas_interpret",
-              resident_max_bytes=1024, chunk_rows=128,
-              occupancy=0.9, skip_occupancy_max=0.5)
-    with pytest.raises(ValueError, match="worklist geometry"):
-        halo_spmm(nbr, wts, table, None, wl_ids=bad_ids,
-                  wl_cnt=jnp.asarray(wl.cnt[:1]),
-                  backend="pallas_interpret", resident_max_bytes=1024,
-                  chunk_rows=128, occupancy=0.3, skip_occupancy_max=0.5)
+    # The ladder's own selection never consults the worklist: past the
+    # budget it gathers, so the (bogus) worklist goes unread; only the
+    # skip backend named above reads it — the geometry guard makes the
+    # choice observable.
+    out = halo_spmm(nbr, wts, table, None, wl_ids=bad_ids,
+                    wl_cnt=jnp.asarray(wl.cnt[:1]),
+                    backend="pallas_interpret", resident_max_bytes=1024,
+                    chunk_rows=128)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(halo_spmm_ref(nbr, wts, table)),
+                               atol=1e-5, rtol=1e-5)
     # jnp backend ignores the worklist entirely.  Compared jitted against
     # jitted: XLA fuses the reference's multiply-reduce differently from
     # op-by-op execution, so eager vs jitted differs at the ULP level.

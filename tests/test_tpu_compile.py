@@ -1,13 +1,15 @@
 """Compile the main-path aggregation kernels for a described TPU v5e.
 
 No chip is needed: the TPU compiler compiles for a topology that is
-described, not attached.  Each case lowers one rung of the ``halo_spmm``
-ladder (resident / dense stream / chunk-skipping stream, fp32 and int8)
-at real widths — hidden 128, chunk 512 — and asserts that the Pallas
-kernel survived into the executable (``tpu_custom_call``) and that its
-buffers fit one chip.  A kernel that only ever ran in interpret mode can
-pass every CPU test and still be refused by Mosaic; these cases catch
-that before any chip time is spent.
+described, not attached.  Each case lowers one Pallas kernel of the
+``halo_spmm`` ladder (the resident rung, and the one-hot dense and
+chunk-skipping streams by name; fp32 and int8) at real widths — hidden
+128, chunk 512 — and asserts that the kernel survived into the
+executable (``tpu_custom_call``) and that its buffers fit one chip.  A
+kernel that only ever ran in interpret mode can pass every CPU test and
+still be refused by Mosaic; these cases catch that before any chip time
+is spent.  The row-gather rung is compiled at the products-sim ×10
+cell's shapes, with a guard on its temporary memory.
 
 The four-device cases compile, over the described 2x2 mesh, each
 program that aggregates while its operands are sharded: the collective
@@ -83,8 +85,8 @@ def _args(one_chip, n_tab, dtype, *, scale, pred=False, worklist=False):
     return args
 
 
-def _compile(args, **static):
-    fn = jax.jit(functools.partial(halo_spmm, backend="pallas",
+def _compile(args, backend="pallas", **static):
+    fn = jax.jit(functools.partial(halo_spmm, backend=backend,
                                    chunk_rows=CHUNK, **static))
     names = list(args)
     compiled = jax.jit(lambda *a: fn(**dict(zip(names, a)))).lower(
@@ -98,44 +100,47 @@ def _compile(args, **static):
     return compiled
 
 
-# (rung, table rows, storage dtype, scales?, static knobs)
+# (kernel, table rows, storage dtype, scales?, backend, rung the ladder
+# selects for the table)
 CASES = [
-    ("resident", 2048, jnp.float32, False, {}),
-    ("resident", 2048, jnp.int8, True, {}),
-    ("stream", 8192, jnp.float32, False, {}),
-    ("stream", 8192, jnp.int8, True, {}),
-    ("skip", 8192, jnp.float32, False, {"occupancy": 0.25}),
-    ("skip", 8192, jnp.int8, True, {"occupancy": 0.25}),
+    ("resident", 2048, jnp.float32, False, "pallas", "resident"),
+    ("resident", 2048, jnp.int8, True, "pallas", "resident"),
+    ("stream", 8192, jnp.float32, False, "pallas_stream", "gather"),
+    ("stream", 8192, jnp.int8, True, "pallas_stream", "gather"),
+    ("skip", 8192, jnp.float32, False, "pallas_skip", "gather"),
+    ("skip", 8192, jnp.int8, True, "pallas_skip", "gather"),
 ]
 
 
-@pytest.mark.parametrize("rung,n_tab,dtype,scale,static", CASES,
+@pytest.mark.parametrize("kernel,n_tab,dtype,scale,backend,rung", CASES,
                          ids=[f"{c[0]}-{jnp.dtype(c[2]).name}"
                               for c in CASES])
-def test_kernel_compiles_for_v5e(one_chip, no_persistent_cache, rung,
-                                 n_tab, dtype, scale, static):
-    worklist = rung == "skip"
-    assert select_kernel((n_tab, HIDDEN), dtype, has_scale=scale,
-                         has_worklist=worklist,
-                         occupancy=static.get("occupancy")) == rung
+def test_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kernel,
+                                 n_tab, dtype, scale, backend, rung):
+    """Each Pallas kernel compiles: the resident one as the ladder
+    selects it, the one-hot streams by name (past the budget the ladder
+    gathers)."""
+    assert select_kernel((n_tab, HIDDEN), dtype, has_scale=scale) == rung
     _compile(_args(one_chip, n_tab, dtype, scale=scale,
-                   worklist=worklist), **static)
+                   worklist=kernel == "skip"), backend=backend)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8],
                          ids=["float32", "int8"])
 def test_predictor_epilogue_compiles_for_v5e(one_chip, no_persistent_cache,
                                              dtype):
-    """The SAT epilogue's second product over the same one-hot tile."""
+    """The SAT epilogue's second product over the same one-hot tile, in
+    the one-hot stream kernel (by name: the ladder gathers this table)."""
     scale = dtype == jnp.int8
     args = _args(one_chip, 8192, dtype, scale=scale, pred=True)
-    _compile(args, gamma=0.5)
+    _compile(args, backend="pallas_stream", gamma=0.5)
 
 
 def test_kernel_backward_compiles_for_v5e(one_chip, no_persistent_cache):
     """The epoch differentiates through the in-subgraph aggregation: the
-    forward kernel and the reference VJP compile together, batched over
-    subgraphs as the epoch's vmap batches them."""
+    forward rung the ladder selects (the gather, for this table) and the
+    reference VJP compile together, batched over subgraphs as the epoch's
+    vmap batches them."""
     m = 2
 
     def loss(wts, table, nbr):
@@ -150,7 +155,37 @@ def test_kernel_backward_compiles_for_v5e(one_chip, no_persistent_cache):
         sds((m, ROWS, DEG), jnp.float32), sds((m, 8192, HIDDEN),
                                               jnp.float32),
         sds((m, ROWS, DEG), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "jit(halo_spmm_gather)" in compiled.as_text()
+
+
+# The products-sim ×10 cell's aggregation calls: 8 parts of 15,752 rows;
+# the halo side's width-80 ELL against its 104,249-row slab, the local
+# side's width-48 ELL against its 15,753-row table.
+CELL_PARTS, CELL_ROWS = 8, 15752
+
+
+@pytest.mark.parametrize("deg,n_tab", [(80, 104249), (48, 15753)],
+                         ids=["halo", "local"])
+def test_gather_rung_compiles_for_v5e(one_chip, no_persistent_cache, deg,
+                                      n_tab):
+    """The row gather at the cell's shapes, vmapped over the parts as the
+    epoch runs it.  Its temporary memory stays under one ``(parts, rows,
+    deg, 128)`` float32 intermediate, the whole set of gathered rows that
+    the plain take-then-sum form keeps live (5.2 GB on the halo side)."""
+    assert select_kernel((n_tab, HIDDEN), jnp.float32) == "gather"
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct((CELL_PARTS,) + shape, dt,
+                                    sharding=one_chip)
+    fn = jax.vmap(functools.partial(halo_spmm, backend="pallas"))
+    compiled = jax.jit(fn).lower(
+        sds((CELL_ROWS, deg), jnp.int32), sds((CELL_ROWS, deg), jnp.float32),
+        sds((n_tab, HIDDEN), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "jit(halo_spmm_gather)" in text
+    assert "tpu_custom_call" not in text
+    whole = CELL_PARTS * CELL_ROWS * deg * HIDDEN * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < whole
 
 
 # -- four devices: the sharded programs ------------------------------------
@@ -262,3 +297,37 @@ def test_sharded_query_compiles_for_v5e_2x2(mesh4, small_graph,
         q_rows).compile()
     # The in-shard and the pulled-halo aggregation of the top layer.
     _holds_kernels(compiled, 2)
+
+
+@pytest.mark.parametrize("storage", ["fp32", "int8"])
+def test_gather_rung_compiles_in_shard_map_for_v5e_2x2(
+        mesh4, small_graph, no_persistent_cache, storage):
+    """The collective epoch with the halo tables past a shrunken resident
+    budget (the in-subgraph tables keep the default one): the row gather
+    runs inside the manual ``shard_map`` with its varying-axes types
+    checked, an int8 store dequantized before it."""
+    import dataclasses
+
+    from repro.core import (HaloPrecision, TrainSettings, init_state,
+                            make_epoch_fn)
+    from repro.launch.train_gnn import subgraph_shardings
+    from repro.optim import adam
+
+    data, cfg = small_graph
+    cfg = dataclasses.replace(cfg, resident_max_bytes=1024)
+    opt = adam(5e-3)
+    settings = TrainSettings(sync_interval=10, mode="digest",
+                             pull_mode="collective",
+                             precision=HaloPrecision(storage))
+    state = jax.eval_shape(lambda: init_state(cfg, opt, data,
+                                              precision=settings.precision))
+    tdata = {k: v for k, v in data.items() if not k.startswith("_")}
+    data_sh, state_sh = subgraph_shardings(tdata, state, mesh4)
+    epoch = jax.jit(make_epoch_fn(cfg, opt, settings, mesh=mesh4),
+                    in_shardings=(state_sh, data_sh))
+    text = epoch.lower(_placed(state, state_sh),
+                       _placed(tdata, data_sh)).compile().as_text()
+    whiles = [line for line in text.splitlines()
+              if " while(" in line and "jit(halo_spmm_gather)" in line]
+    assert len(whiles) == cfg.num_layers
+    assert "all-to-all" in text
