@@ -51,7 +51,7 @@ def program_numbers(cell, gd, g, seed: int, used: list, counter) -> tuple:
     prog = cell_mod.build(cell, gd, seed, used)
     steps = cell_mod.first_epochs(prog, interval)
     cell_mod.window(prog, steps, 0.0, counter)
-    got = cell_mod.outputs(steps, cell.config["adam_b1"])
+    got = cell_mod.outputs(steps, cell)
     del prog, steps
     ref, params0 = harness.reference_outputs(cell, gd, g, seed,
                                              got["params3"])

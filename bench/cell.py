@@ -78,7 +78,7 @@ def build(cell, gd, seed: int, devices: list) -> Program:
     c, t = cell.config, cell.traffic
     cfg = model_config(gd.graph, gd.data, model=c["model"],
                        hidden_dim=c["hidden_dim"],
-                       num_layers=c["num_layers"],
+                       num_layers=c["num_layers"], heads=c["heads"],
                        normalize=c["normalize"])
     opt = adam(c["learning_rate"], b1=c["adam_b1"], b2=c["adam_b2"],
                eps=c["adam_eps"])
@@ -122,7 +122,8 @@ class Steps:
 def _compared(state: dict, epoch: int, sync_interval: int) -> dict:
     """What ``compare.numbers`` reads of the state after ``epoch``:
     Adam's first moment and the store after epoch 1, the parameters after
-    epoch 3, and the halo tables pulled at epoch p."""
+    epoch 3, and the cache that holds the halo tables pulled at epoch p
+    (``outputs`` reads them through the model's ``slab``)."""
     p = compare.pull_epoch(sync_interval)
     out = {}
     if epoch == 1:
@@ -131,7 +132,7 @@ def _compared(state: dict, epoch: int, sync_interval: int) -> dict:
     if epoch == 3:
         out["params3"] = state["params"]
     if epoch == p:
-        out["slab"] = state["cache"]["data"]
+        out["cache"] = state["cache"]
     return out
 
 
@@ -156,6 +157,20 @@ def _collect(steps: Steps, upto: int) -> None:
         steps.compared.update({(k, epoch): v for k, v in host.items()})
 
 
+def _release(read: dict, steps: Steps, *live) -> None:
+    """Free the state an epoch read, once that epoch has ended, before the
+    next epoch is queued; arrays it shares with the ``live`` states or
+    with a pending host copy stay.  The runtime frees an ended epoch's
+    input on its own, but late enough, now and then, that the next
+    dispatch allocates while the old cache slab is still held: the
+    allocator's peak then reads one slab (854 MB on products-120k) more
+    in some processes than in others."""
+    keep = {id(a) for a in jax.tree.leaves((live, steps.pending))}
+    for a in jax.tree.leaves(read):
+        if id(a) not in keep and not a.is_deleted():
+            a.delete()
+
+
 def first_epochs(prog: Program, sync_interval: int) -> Steps:
     """Set-up: epoch 1, which loads every program the window runs, and
     the epochs before the first pull of pushed rows (epoch p), so that
@@ -163,9 +178,11 @@ def first_epochs(prog: Program, sync_interval: int) -> Steps:
     program's own first ones, through its one compiled call."""
     steps = Steps(sync_interval=sync_interval)
     for epoch in range(1, max(compare.pull_epoch(sync_interval), 2)):
+        read = prog.state
         _dispatch(prog, steps)
         jax.block_until_ready(prog.state)
         _collect(steps, epoch)
+        _release(read, steps, prog.state)
     return steps
 
 
@@ -173,15 +190,17 @@ def window(prog: Program, steps: Steps, seconds: float,
            counter: CompileCounter) -> None:
     """Run whole epochs until ``seconds`` have passed, and at least
     through the last epoch compared, keeping one epoch queued behind the
-    one the device runs.  The window is all the time from the first
+    one the device runs; the state an ended epoch read is freed before
+    the next is queued.  The window is all the time from the first
     dispatch to the end of the last epoch."""
     ann = jax.profiler.TraceAnnotation
     before = counter.count
     need = compare.last_epoch(steps.sync_interval) - len(steps.losses)
     t0 = time.perf_counter()
     with ann("bench/window"):
-        n = 0
+        n, reads = 0, []
         while True:
+            reads.append(prog.state)
             with ann("bench/dispatch"):
                 _dispatch(prog, steps)
             n += 1
@@ -189,6 +208,7 @@ def window(prog: Program, steps: Steps, seconds: float,
                 with ann("bench/block"):
                     steps.losses[-2].block_until_ready()
                 _collect(steps, len(steps.losses) - 1)
+                _release(reads.pop(0), steps, reads, prog.state)
                 # Epoch n, queued now, ends about one mean epoch later:
                 # the window closes at the first epoch end past seconds.
                 if (n >= need and (time.perf_counter() - t0) * n / (n - 1)
@@ -202,9 +222,11 @@ def window(prog: Program, steps: Steps, seconds: float,
     _collect(steps, len(steps.losses))
 
 
-def outputs(steps: Steps, b1: float) -> dict:
+def outputs(steps: Steps, cell) -> dict:
     """What the program produced in the epochs compared, on the host, in
-    the form ``compare.numbers`` takes."""
+    the form ``compare.numbers`` takes: the halo tables as the cell's
+    model file reads them from the pulled cache."""
+    b1 = cell.config["adam_b1"]
     p = compare.pull_epoch(steps.sync_interval)
     f32 = lambda tree: jax.tree.map(lambda a: np.asarray(a, np.float32),
                                     tree)
@@ -215,7 +237,7 @@ def outputs(steps: Steps, b1: float) -> dict:
                                   f32(got["m1", 1])),
             "params3": f32(got["params3", 3]),
             "stores": {1: list(got["store", 1])},
-            "slab": got["slab", p]}
+            "slab": cell.model.slab(got["cache", p])}
 
 
 def peak_bytes(devices: list) -> int:

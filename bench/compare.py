@@ -62,10 +62,10 @@ def _store_gap(got: list, want: list, store_ids: np.ndarray) -> float:
     return float(max(gaps, default=0.0))
 
 
-def _slab_gap(got: np.ndarray, want: list, halo_ids: np.ndarray) -> float:
-    """``got`` (M, L-1, >= H, hidden) in the program's halo order, ``want``
-    per layer (N, hidden) per node; rows matched through ``halo_ids``
-    (M, H), N at padding."""
+def _slab_gap(got: list, want: list, halo_ids: np.ndarray) -> float:
+    """``got`` per hidden layer (M, >= H, width) in the program's halo
+    order, ``want`` per layer (N, width) per node; rows matched through
+    ``halo_ids`` (M, H), N at padding."""
     n = want[0].shape[0] if want else 0
     valid = halo_ids < n
     gaps, norms = [], []
@@ -73,7 +73,7 @@ def _slab_gap(got: np.ndarray, want: list, halo_ids: np.ndarray) -> float:
         ids = halo_ids[m][valid[m]]
         for ell, table in enumerate(want):
             w = table[ids]
-            g = np.asarray(got[m, ell, :halo_ids.shape[1]])[valid[m]]
+            g = np.asarray(got[ell][m, :halo_ids.shape[1]])[valid[m]]
             gaps.append(np.linalg.norm(g - w, axis=1))
             norms.append(np.linalg.norm(w, axis=1))
     if not gaps:
@@ -86,10 +86,10 @@ def numbers(got: dict, ref: dict, params0, ids: dict,
             sync_interval: int) -> dict:
     """``got`` and ``ref`` each hold ``losses`` (epochs 1 to p + 1),
     ``grad1`` and ``params3`` (trees), ``stores`` (by push epoch, per
-    layer; epoch 1 is compared) and ``slab`` / ``tables`` (the halo tables pulled at epoch
-    p): ``got``'s in the program's layout (store slots; (M, L-1, H,
-    hidden) halo rows), ``ref``'s per node, matched through ``ids``'
-    ``store_ids`` and ``halo_ids``."""
+    layer; epoch 1 is compared) and ``slab`` / ``tables`` (the halo
+    tables pulled at epoch p, per hidden layer): ``got``'s in the
+    program's layout (store slots; (M, H, width) halo rows), ``ref``'s
+    per node, matched through ``ids``' ``store_ids`` and ``halo_ids``."""
     p = pull_epoch(sync_interval)
     last = last_epoch(sync_interval)
     losses = np.asarray(got["losses"][:last], np.float64)
@@ -126,9 +126,7 @@ def in_program_layout(out: dict, ids: dict, sync_interval: int) -> dict:
     halo = np.minimum(ids["halo_ids"], max(n - 1, 0))
     return dict(out, stores={r: [s[slot] for s in st]
                              for r, st in out["stores"].items()},
-                slab=np.stack([np.stack([t[halo[m]] for t in tables])
-                               for m in range(halo.shape[0])])
-                if tables else np.zeros(halo.shape + (0,), np.float32))
+                slab=[t[halo] for t in tables])
 
 
 def verdict(nums: dict, limits: dict) -> tuple[bool, list]:

@@ -14,15 +14,23 @@ features:
 A gradient flows into a table only where it depends on the parameters:
 never into the stale halo rows nor the layer-0 features.
 
-``epoch_flops`` adds the dense transforms, forward and backward (the
-weight gradient always, the input gradient above layer 0), to the
+The count is per model: ``epoch`` hands the parts' shapes to the
+model's file, ``bench/models/<model>.py`` (``work``), which adds up its
+aggregation calls by ``aggregation`` and its dense transforms.
+``epoch_flops`` adds the dense transforms, forward and backward, to the
 aggregation.  Elementwise work (activations, normalization, softmax) and
 the optimizer are not counted.  All counts are for all parts together,
 per epoch without a pull or push.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import numpy as np
+
+from bench import spec
+
+ROOT = Path(__file__).resolve().parents[1]
 
 VALUE = 4   # bytes of an fp32 value or an int32 id
 
@@ -51,30 +59,14 @@ def part_shapes(data: dict) -> list[dict]:
             for m in range(data["local_ids"].shape[0])]
 
 
-def epoch(config: dict, in_dim: int, num_classes: int,
-          data: dict) -> dict:
+def epoch(config: dict, in_dim: int, num_classes: int, data: dict,
+          root: Path = ROOT) -> dict:
     """``agg_flops``, ``agg_bytes``, ``dense_flops`` and ``epoch_flops``
-    of one epoch of the GCN ``config`` over the partitioned ``data``."""
-    if config["model"] != "gcn":
-        raise ValueError(f"no count for model {config['model']!r}")
-    layers, hidden = config["num_layers"], config["hidden_dim"]
-    agg_f = agg_b = dense = 0
-    for p in part_shapes(data):
-        n, halo = p["rows"], p["halo"]
-        for ell in range(layers):
-            din = in_dim if ell == 0 else hidden
-            dout = num_classes if ell == layers - 1 else hidden
-            # Forward and weight gradient; the input gradient above
-            # layer 0.
-            dense += 2 * n * din * dout * (3 if ell > 0 else 2)
-            for nnz, table_rows, grad in ((p["nnz_in"], n, ell > 0),
-                                          (p["nnz_out"], halo, False)):
-                f, b = aggregation(nnz, din, n, table_rows,
-                                   grad_table=grad)
-                agg_f += f
-                agg_b += b
-    return {"agg_flops": agg_f, "agg_bytes": agg_b, "dense_flops": dense,
-            "epoch_flops": agg_f + dense}
+    of one epoch of ``config`` over the partitioned ``data``, as its
+    model's file under ``<root>/bench/models`` counts them (``work``).
+    A model with no file is refused."""
+    return spec.load_model(root, config["model"]).work(
+        config, in_dim, num_classes, part_shapes(data))
 
 
 def ell_fill(data: dict) -> dict:

@@ -97,8 +97,8 @@ def assignment(data: dict, num_nodes: int) -> np.ndarray:
 def reference_model(cell: spec.Cell) -> reference.Model:
     c, g = cell.config, cell.graph
     return reference.Model(
-        num_layers=c["num_layers"], hidden=c["hidden_dim"],
-        num_classes=g["num_classes"],
+        model=cell.model, heads=c["heads"], num_layers=c["num_layers"],
+        hidden=c["hidden_dim"], num_classes=g["num_classes"],
         normalize=c["normalize"], lr=c["learning_rate"], b1=c["adam_b1"],
         b2=c["adam_b2"], eps=c["adam_eps"],
         sync_interval=cell.traffic["sync_interval"])
@@ -183,7 +183,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         f"slots filled (nnz / slots): in {fill['in']:.4f}, out "
         f"{fill['out']:.4f}")
     work = counts.epoch(cell.config, cell.graph["feature_dim"],
-                        cell.graph["num_classes"], gd.data)
+                        cell.graph["num_classes"], gd.data, root)
     log("required work per epoch: " + ", ".join(
         f"{k} {v:.6g}" for k, v in work.items()))
 
@@ -223,7 +223,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     losses = [float(x) for x in steps.losses]
     log("losses: " + " ".join(f"{x:.6f}" for x in losses))
     log(f"peak after the window {mem} B")
-    got = cell_mod.outputs(steps, cell.config["adam_b1"])
+    got = cell_mod.outputs(steps, cell)
     like = got["params3"]
     hlo, compile_s = prog.hlo, prog.compile_s
     epochs, window_s = steps.epochs, steps.window_s

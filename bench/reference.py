@@ -1,17 +1,25 @@
-"""Plain reference of DIGEST training: a GCN over an edge list.
+"""Plain reference of DIGEST training over an edge list.
 
 Independent of the program: it imports nothing of ``repro`` and takes
 only the benchmark's graph (its edge list, labels, train mask), the
 node-to-part assignment, and the features and weights that ``inputs.py``
-draws from the seed.  It follows the paper's semantics directly, over
-all N nodes at once rather than per padded subgraph:
+draws from the seed.  This module is the loop every model shares; a
+model's own arithmetic is its file ``bench/models/<model>.py``
+(``spec.load_model``): ``layer``, one layer over the edge sets below,
+and ``pull``, what a pull puts into the halo tables.  It follows the
+paper's semantics directly, over all N nodes at once rather than per
+padded subgraph:
 
-  * P = D^-1/2 (A + I) D^-1/2 with global degrees (GCN weights);
+  * the edge sets hold P = D^-1/2 (A + I) D^-1/2 with global degrees
+    (GCN weights, which a model that does not read them ignores);
   * an edge whose endpoints lie in one part reads the neighbour's fresh
     representation; an edge across parts reads the stale halo table of
     that layer (layer 0: the raw features, always fresh);
-  * epoch r (from 1) pulls the store into the halo tables when
-    r % N == 0 and pushes every hidden layer's L2-normalized rows when
+  * every hidden layer is followed by relu and, with ``normalize``, an
+    L2 normalization of each row (Algorithm 1 line 11): its rows are
+    what a push stores;
+  * epoch r (from 1) pulls the store into the halo tables (the model's
+    ``pull``) when r % N == 0 and pushes every hidden layer's rows when
     (r - 1) % N == 0;
   * the loss is the mean over parts of each part's mean cross-entropy
     over its training nodes; the gradient is that loss's gradient, and
@@ -37,6 +45,8 @@ CHUNK = 1 << 19
 
 @dataclasses.dataclass(frozen=True)
 class Model:
+    model: object       # the model file, bench/models/<name>.py
+    heads: int
     num_layers: int
     hidden: int
     num_classes: int
@@ -83,7 +93,7 @@ def prepare(edges: np.ndarray, assign: np.ndarray, labels: np.ndarray,
             "loss_w": jnp.asarray(lw), "part": assign}
 
 
-def _edge_sum(e: dict, coef, table, n: int):
+def edge_sum(e: dict, coef, table, n: int):
     """out[d] = sum over edges (s, d) of coef_e * table[s]."""
     def body(acc, blk):
         s, d, c = blk
@@ -95,19 +105,13 @@ def _edge_sum(e: dict, coef, table, n: int):
     return jax.lax.scan(body, acc, (e["src"], e["dst"], coef))[0]
 
 
-def _gcn_layer(p, h, tab, g, n, prec):
-    ein, eout = g["edges"]["in"], g["edges"]["out"]
-    agg = (_edge_sum(ein, ein["w"], h, n)
-           + _edge_sum(eout, eout["w"], h if tab is None else tab, n))
-    return jnp.matmul(agg, p["w"].astype(h.dtype), precision=prec) + p["b"]
-
-
 def _forward(model: Model, params, x, cache, g, prec):
     n = x.shape[0]
     h, reps = x, []
     for ell in range(model.num_layers):
         tab = None if ell == 0 else jax.lax.stop_gradient(cache[ell - 1])
-        out = _gcn_layer(params[f"layer_{ell}"], h, tab, g, n, prec)
+        out = model.model.layer(model, params[f"layer_{ell}"], h, tab, g,
+                                n, prec)
         if ell < model.num_layers - 1:
             out = jax.nn.relu(out)
             if model.normalize:
@@ -179,13 +183,15 @@ def run(model: Model, g: dict, x, params0, epochs: int,
     v = jax.tree.map(jnp.zeros_like, params)
     hid = range(model.num_layers - 1)
     store = [jnp.zeros((n, model.hidden), dtype) for _ in hid]
-    cache = list(store)
+    # Before the first pull the halo tables hold what a pull of the
+    # empty store would.
+    cache = model.model.pull(model, store, params, prec)
     out = {"losses": [], "stores": {}, "tables": {}}
     keep = fault != "unchanged"
     for r in range(1, epochs + 1):
         if r % model.sync_interval == 0:
             if keep and fault != "no_pull":
-                cache = list(store)
+                cache = model.model.pull(model, store, params, prec)
             out["tables"][r] = [np.asarray(c, np.float32) for c in cache]
         new = _step(model, prec, params, m, v, jnp.asarray(r - 1, dtype),
                     x, cache, g)

@@ -2,6 +2,7 @@
 skipped): it is correct, and each fault the cell can have, planted in
 the timed path underneath, turns ``correct`` false; so does the control,
 the reference computed in bfloat16 in the program's place."""
+import dataclasses
 import json
 import os
 import shutil
@@ -156,6 +157,27 @@ def _altered_pulled_row(monkeypatch):
         slab = real(*args)
         return dict(slab, data=slab["data"].at[0, 0, 0].multiply(-1.0))
     monkeypatch.setattr(halo_exchange, "pull_slab", pull_slab)
+
+
+def test_build_passes_the_configuration_heads(tiny, monkeypatch):
+    """The configuration's ``heads`` reaches the program's GNNConfig: a
+    GAT configuration with two heads builds and compiles a two-head
+    epoch."""
+    from repro.launch import train_gnn
+    real, made = train_gnn.model_config, []
+
+    def model_config(*args, **kw):
+        made.append(real(*args, **kw))
+        return made[-1]
+    monkeypatch.setattr(train_gnn, "model_config", model_config)
+    cell = spec.load_cell(tiny, "gcn-products120k-n10")
+    gat = dataclasses.replace(cell, config=dict(cell.config, model="gat",
+                                                heads=2))
+    gd = graphs.load(tiny, cell.graph, cell.config["num_parts"],
+                     tiny / "cache")
+    prog = cell_mod.build(gat, gd, 3, jax.devices()[:1])
+    assert [(c.model, c.heads) for c in made] == [("gat", 2)]
+    assert prog.state["params"]["layer_0"]["a_src"].shape == (2, 64)
 
 
 @pytest.mark.parametrize("fault", [_unchanged_state, _half_batch,

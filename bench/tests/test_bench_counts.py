@@ -1,14 +1,16 @@
 """``bench/counts.py`` against hand counts on a tiny partitioned graph."""
-import os
+import json
+import shutil
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+REPO = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(REPO))
 
-from bench import counts  # noqa: E402
+from bench import counts, spec  # noqa: E402
 
 
 def tiny_data():
@@ -59,14 +61,25 @@ def test_aggregation_by_hand():
     assert (f2, b2) == (2 * f, 2 * b)
 
 
-@pytest.mark.parametrize("model", ["gcn", "gat"])
-def test_epoch_by_hand(model):
+@pytest.mark.parametrize("model", ["gcn", "nomodel"])
+def test_epoch_by_hand(model, tmp_path):
     cfg = {"model": model, "num_layers": 2, "hidden_dim": 8, "heads": 2}
     if model != "gcn":
-        # Only the GCN's work is counted; another model is refused, not
-        # counted as a GCN.
-        with pytest.raises(ValueError, match="no count"):
+        # A model with no file under bench/models is refused, not counted
+        # as a GCN: by the count, and when a cell naming it is loaded.
+        missing = "bench/models/nomodel.py"
+        with pytest.raises(FileNotFoundError, match=missing):
             counts.epoch(cfg, in_dim=5, num_classes=3, data=tiny_data())
+        shutil.copy(REPO / "BENCHMARK.json", tmp_path)
+        shutil.copytree(REPO / "bench", tmp_path / "bench",
+                        ignore=shutil.ignore_patterns(
+                            "__pycache__", ".graph_cache", ".traces",
+                            "tests", "testdata"))
+        path = tmp_path / "bench" / "configs" / "digest-gcn.json"
+        path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                        model=model)))
+        with pytest.raises(FileNotFoundError, match=missing):
+            spec.load_cell(tmp_path, "gcn-products120k-n10")
         return
     got = counts.epoch(cfg, in_dim=5, num_classes=3, data=tiny_data())
     agg = dense = 0
