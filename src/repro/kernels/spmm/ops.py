@@ -31,6 +31,14 @@ The rungs compute the forward product; the backward pass is the VJP of
 the jnp reference (a transposed ELL product XLA lowers as gather +
 scatter-add), attached through ``jax.custom_vjp``, so every backend
 differentiates identically.
+
+``multihead_spmm`` (GAT's aggregation) weights each head's slice of a
+``(T, heads·dh)`` table by its own attention.  It takes no backend: one
+form runs everywhere, the row gather's slot ``scan``, each slot's whole
+row gathered once and weighted per head, with a backward of its own
+(``_multihead_gather``), so no Pallas kernel sees a per-head block and
+the CPU runs the forward and backward the chip runs.  Its oracle is
+:func:`repro.kernels.spmm.ref.multihead_spmm_ref`.
 """
 from __future__ import annotations
 
@@ -135,14 +143,101 @@ def halo_spmm_gather(nbr: jax.Array, wts: jax.Array, data: jax.Array,
             acc = acc + wt[:, None] * jnp.take(table, ids, axis=0)
         return acc, None
 
-    acc = jnp.zeros((nbr.shape[0], data.shape[1]), jnp.float32)
-    # Inside a shard_map the carry varies as the operands do (check_vma).
-    vma = frozenset().union(*(jax.typeof(x).vma for x in (
-        nbr, wts, data, scale, pdata, pscale) if x is not None))
-    if vma:
-        acc = jax.lax.pcast(acc, tuple(sorted(vma)), to="varying")
+    acc = _varying(jnp.zeros((nbr.shape[0], data.shape[1]), jnp.float32),
+                   nbr, wts, data, scale, pdata, pscale)
     out, _ = jax.lax.scan(slot, acc, (nbr.T, w.T))
     return out
+
+
+def _varying(carry: jax.Array, *operands) -> jax.Array:
+    """A loop carry typed as varying over the mesh axes any operand varies
+    over: inside a ``shard_map`` the varying-axes check (``check_vma``)
+    asks the carry to vary as the operands do."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands
+                              if x is not None))
+    if vma:
+        carry = jax.lax.pcast(carry, tuple(sorted(vma)), to="varying")
+    return carry
+
+
+def _head_weights(att: jax.Array, width: int) -> jax.Array:
+    """``(rows, heads)`` weights spread over a ``(rows, heads·dh)`` row:
+    head h's weight on its ``dh`` columns."""
+    return jnp.repeat(att, width // att.shape[-1], axis=-1)
+
+
+def _multihead_forward(nbr: jax.Array, att: jax.Array,
+                       table: jax.Array) -> jax.Array:
+    """:func:`~repro.kernels.spmm.ref.multihead_spmm_ref`'s sum accumulated
+    in float32 one ELL slot at a time, over the transposed ELL as in
+    :func:`halo_spmm_gather`: each step gathers one ``(rows, heads·dh)``
+    slice of table rows, so the ``(rows, deg, heads·dh)`` gathered rows are
+    never live whole."""
+    width = table.shape[1]
+
+    def slot(acc, x):
+        ids, a = x
+        return acc + _head_weights(a, width) * jnp.take(table, ids,
+                                                        axis=0), None
+
+    acc = _varying(jnp.zeros((nbr.shape[0], width), jnp.float32),
+                   nbr, att, table)
+    out, _ = jax.lax.scan(slot, acc, (nbr.T, jnp.moveaxis(att, 1, 0)))
+    return out
+
+
+_multihead_gather = jax.custom_vjp(_multihead_forward)
+
+
+def _multihead_gather_fwd(nbr, att, table):
+    return _multihead_forward(nbr, att, table), (nbr, att, table)
+
+
+def _multihead_gather_bwd(res, g):
+    """Slot by slot again: slot k's attention gradient is the per-head sum
+    over ``dh`` of ``table[nbr[:, k]] * g``, and its table gradient a
+    scatter-add of ``att[:, k] * g`` into a ``(T, heads·dh)`` carry;
+    neither keeps a ``(rows, deg, heads·dh)`` array live.  Where the table
+    takes no gradient (a stale halo under stop_gradient) the carry is
+    dead, and the compiler drops it with its scatter-adds."""
+    nbr, att, table = res
+    rows, width = g.shape
+    heads = att.shape[-1]
+    g = g.astype(jnp.float32)
+
+    def slot(d_table, x):
+        ids, a = x
+        picked = jnp.take(table, ids, axis=0) * g
+        d_att = picked.reshape(rows, heads, -1).sum(axis=-1)
+        return d_table.at[ids].add(_head_weights(a, width) * g), d_att
+
+    d_table = _varying(jnp.zeros(table.shape, jnp.float32), nbr, att, table,
+                       g)
+    d_table, d_att = jax.lax.scan(slot, d_table,
+                                  (nbr.T, jnp.moveaxis(att, 1, 0)))
+    return (None, jnp.moveaxis(d_att, 0, 1).astype(att.dtype),
+            d_table.astype(table.dtype))
+
+
+_multihead_gather.defvjp(_multihead_gather_fwd, _multihead_gather_bwd)
+
+
+@jax.jit
+def multihead_spmm(nbr: jax.Array, att: jax.Array,
+                   table: jax.Array) -> jax.Array:
+    """Multi-head neighbor aggregation, GAT's: ``out[i, h·dh:(h+1)·dh] =
+    Σ_k att[i,k,h]·table[nbr[i,k], h·dh:(h+1)·dh]``, accumulated one ELL
+    slot at a time (:func:`_multihead_forward`) with its own backward.
+
+    Args:
+      nbr:   (rows, deg) int32 table row ids, sentinel ``T - 1``.
+      att:   (rows, deg, heads) attention, 0 at padding slots.
+      table: (T, heads·dh) rows, head-major, the zero sentinel row last.
+    Returns:
+      (rows, heads·dh) float32.
+    """
+    return _multihead_gather(nbr, att.astype(jnp.float32),
+                             table.astype(jnp.float32))
 
 
 def _pad_dim(x: jax.Array, axis: int, multiple: int,

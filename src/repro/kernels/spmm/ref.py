@@ -12,6 +12,18 @@ def spmm_ref(nbr: jax.Array, wts: jax.Array, table: jax.Array) -> jax.Array:
     return jnp.sum(w * gathered.astype(jnp.float32), axis=1)
 
 
+def multihead_spmm_ref(nbr: jax.Array, att: jax.Array,
+                       table: jax.Array) -> jax.Array:
+    """out[i, h·dh:(h+1)·dh] = sum_k att[i,k,h] * table[nbr[i,k],
+    h·dh:(h+1)·dh] — per-head weights over a ``(T, heads·dh)`` table,
+    vectorized gather form."""
+    rows, deg, heads = att.shape
+    gathered = jnp.take(table, nbr, axis=0).astype(jnp.float32)
+    gathered = gathered.reshape(rows, deg, heads, -1)   # (rows, deg, h, dh)
+    w = att.astype(jnp.float32)[..., None]
+    return jnp.sum(w * gathered, axis=1).reshape(rows, -1)
+
+
 def halo_spmm_ref(nbr: jax.Array, wts: jax.Array, data: jax.Array,
                   scale: jax.Array = None, pdata: jax.Array = None,
                   pscale: jax.Array = None,

@@ -37,7 +37,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.spmm import halo_spmm, spmm
+from repro.kernels.spmm import halo_spmm, multihead_spmm, spmm
 from repro.nn import ParamSpec, dense
 
 Pytree = Any
@@ -129,7 +129,9 @@ class GNNConfig:
     normalize: bool = True        # Algorithm 1 line 11 (L2 per node)
     residual: bool = False
     # Aggregation backend (repro.kernels.spmm.BACKENDS): "auto" runs the
-    # kernel ladder on a TPU and the jnp reference elsewhere.
+    # kernel ladder on a TPU and the jnp reference elsewhere.  GAT's
+    # multi-head aggregation (multihead_spmm) takes none: one form runs
+    # everywhere.
     backend: str = "auto"
     # -- halo_spmm ladder knobs (static; override the module constants
     # of repro.kernels.spmm.ops — None keeps the kernel defaults) -------
@@ -235,19 +237,6 @@ def _sage_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
                 + p["b"])
 
 
-def _multihead_spmm(nbr, att, z_pad, backend):
-    """(S, D, heads) attention × (T, heads, dh) tables → (S, heads·dh).
-
-    One batched aggregation (vmap over the head axis) instead of a Python
-    loop of per-head spmm calls — compiles to a single kernel launch per
-    adjacency side.
-    """
-    per_head = jax.vmap(lambda a, z: spmm(nbr, a, z, backend=backend),
-                        in_axes=(2, 1), out_axes=1)
-    out = per_head(att, z_pad)                    # (S, heads, dh)
-    return out.reshape(out.shape[0], -1)
-
-
 def _gat_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
     S = x_local.shape[0]
     ref = _as_halo_ref(x_halo, struct)
@@ -260,11 +249,9 @@ def _gat_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
             # instead of once per subgraph per epoch — the owner-shard
             # dedup path.  Only the (cheap) attention scores below still
             # use this epoch's a_src.
-            z_out = ref["zdata"].astype(jnp.float32)
+            z_flat = ref["zdata"].astype(jnp.float32)
             if "zscale" in ref:
-                z_out = z_out * ref["zscale"]
-            T = z_out.shape[0]                    # slab rows incl. sentinel
-            z_out = z_out.reshape(T, heads, dh)
+                z_flat = z_flat * ref["zscale"]
         else:
             # Legacy: dequantize the raw halo rows and project here.  When
             # the slab enters vmap unbatched (a shared store slab) this
@@ -281,9 +268,10 @@ def _gat_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
                 if "pscale" in ref:
                     p_out = p_out * ref["pscale"]
                 x_out = x_out + jnp.float32(ref["gamma"].value) * p_out
-            T = x_out.shape[0]                    # slab rows incl. sentinel
-            z_out = jnp.einsum("sd,dhk->shk", x_out,
-                               p["w"])            # (T, heads, dh)
+            z_flat = jnp.einsum("sd,dhk->shk", x_out, p["w"]).reshape(
+                x_out.shape[0], heads * dh)
+        T = z_flat.shape[0]                       # slab rows incl. sentinel
+        z_out = z_flat.reshape(T, heads, dh)
 
     with jax.named_scope(ATTENTION_SCOPE):
         s_dst = jnp.einsum("shk,hk->sh", z_loc, p["a_dst"])   # (S, heads)
@@ -310,11 +298,11 @@ def _gat_layer(cfg, p, x_local, x_halo, struct) -> jax.Array:
         a_out = p_out / denom[:, None, :]
 
     with jax.named_scope(AGGREGATE_SCOPE):
-        z_loc_pad = jnp.concatenate(
-            [z_loc, jnp.zeros((1,) + z_loc.shape[1:], z_loc.dtype)], 0)
-        out = _multihead_spmm(struct["in_nbr"], a_in, z_loc_pad,
-                              cfg.backend)
-        out = out + _multihead_spmm(ref["nbr"], a_out, z_out, cfg.backend)
+        # Head-major (rows, heads·dh) tables: each slot's whole row is
+        # gathered once and weighted per head.
+        z_loc_pad = _pad_sentinel(z_loc.reshape(S, heads * dh))
+        out = multihead_spmm(struct["in_nbr"], a_in, z_loc_pad)
+        out = out + multihead_spmm(ref["nbr"], a_out, z_flat)
     with jax.named_scope(TRANSFORM_SCOPE):
         return out + p["b"]
 

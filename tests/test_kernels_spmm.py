@@ -9,7 +9,8 @@ from hypothesis import given, settings, strategies as st
 from repro.core import halo_exchange as hx
 from repro.kernels.spmm import (BACKENDS, RESIDENT_STRIPE_MAX_BYTES,
                                 halo_spmm, halo_spmm_gather, halo_spmm_ref,
-                                halo_spmm_stream_pallas, resolve_backend,
+                                halo_spmm_stream_pallas, multihead_spmm,
+                                multihead_spmm_ref, resolve_backend,
                                 select_kernel, spmm, spmm_ref)
 
 
@@ -236,3 +237,86 @@ def test_unknown_backend_raises(backend):
                             np.float32)
     with pytest.raises(ValueError, match="unknown aggregation backend"):
         spmm(nbr, wts, table, backend=backend)
+
+
+def _multihead_case(rng, rows, deg, ncols, heads, dh):
+    """ELL ids with padding slots at the sentinel ``ncols`` (a zero row
+    last in the table), attention zero there."""
+    nbr = rng.integers(0, ncols + 1, size=(rows, deg)).astype(np.int32)
+    nbr[:, -1] = ncols                      # a padding slot in every row
+    att = rng.random((rows, deg, heads)).astype(np.float32)
+    att *= (nbr < ncols)[..., None]
+    table = rng.normal(size=(ncols + 1, heads * dh)).astype(np.float32)
+    table[-1] = 0
+    return jnp.asarray(nbr), jnp.asarray(att), jnp.asarray(table)
+
+
+def _per_head_ref(nbr, att, table):
+    """The per-head aggregation as separate ``spmm_ref`` calls."""
+    heads = att.shape[-1]
+    cols = table.shape[1] // heads
+    return jnp.concatenate([
+        spmm_ref(nbr, att[..., h], table[:, h * cols:(h + 1) * cols])
+        for h in range(heads)], axis=1)
+
+
+@pytest.mark.parametrize("fn", [multihead_spmm, multihead_spmm_ref],
+                         ids=["slot_scan", "oracle"])
+@pytest.mark.parametrize("heads,dh", [(4, 32), (1, 7)])
+def test_multihead_spmm_and_its_gradients_match_per_head_spmm(
+        fn, heads, dh):
+    """The multi-head aggregation and its oracle, each with its attention
+    gradient and its table gradient, equal ``jax.grad`` of the per-head
+    reference, padding slots and the sentinel row present."""
+    rng = np.random.default_rng(heads * 100 + dh)
+    nbr, att, table = _multihead_case(rng, 45, 7, 30, heads, dh)
+    cot = jnp.asarray(rng.normal(size=(45, heads * dh)), jnp.float32)
+
+    def loss(fn):
+        return lambda a, t: jnp.sum(fn(nbr, a, t) * cot)
+
+    np.testing.assert_allclose(fn(nbr, att, table),
+                               _per_head_ref(nbr, att, table),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(fn), argnums=(0, 1))(att, table)
+    want = jax.grad(loss(_per_head_ref), argnums=(0, 1))(att, table)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+def test_multihead_gather_takes_no_table_gradient_behind_stop_gradient():
+    """A table that enters under stop_gradient (a stale halo) costs the
+    backward no scatter-add; the attention gradient is unchanged."""
+    rng = np.random.default_rng(5)
+    nbr, att, table = _multihead_case(rng, 40, 5, 20, 4, 8)
+
+    def loss(a, t, stop):
+        t = jax.lax.stop_gradient(t) if stop else t
+        return jnp.sum(multihead_spmm(nbr, a, t) ** 2)
+
+    grads = {stop: jax.grad(loss)(att, table, stop) for stop in (0, 1)}
+    np.testing.assert_allclose(grads[1], grads[0], rtol=1e-6, atol=1e-6)
+    hlo = {stop: jax.jit(jax.grad(loss, argnums=(0, 1)),
+                         static_argnums=2).lower(att, table, stop).as_text()
+           for stop in (0, 1)}
+    assert "scatter" in hlo[0] and "scatter" not in hlo[1]
+
+
+def test_multihead_gradients_with_a_live_and_a_stale_table_of_one_shape():
+    """GAT's layer-0 halo table (projected this epoch, takes a gradient)
+    and a later layer's stale halo table (under stop_gradient) have the
+    same shape, so both calls share one compiled ``multihead_spmm``; the
+    gradient into the live table's projection is still the oracle's."""
+    rng = np.random.default_rng(9)
+    nbr, att, stale = _multihead_case(rng, 20, 5, 30, 4, 8)
+    x = jnp.asarray(rng.normal(size=(31, 12)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(12, 32)), jnp.float32)
+
+    def loss(w, fn):
+        live = fn(nbr, att, x @ w)
+        return jnp.sum(fn(nbr, att * jnp.sum(live),
+                          jax.lax.stop_gradient(stale)))
+
+    np.testing.assert_allclose(jax.grad(loss)(w, multihead_spmm),
+                               jax.grad(loss)(w, multihead_spmm_ref),
+                               rtol=1e-5)
